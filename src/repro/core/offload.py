@@ -2,11 +2,12 @@
 
 A `PlacementPlan` realizes the policy tuple's r_w/r_c fractions as a
 per-leaf assignment of weights (and the KV cache) to memory levels, and —
-on backends that support it — produces shardings with an explicit
-``memory_kind`` so XLA keeps offloaded tensors in host DRAM and streams
-them on use.  The CPU validation backend has a single memory space; there
-the plan is exercised logically (page store + engine double buffer) and
-its timing modeled by core.cgopipe / core.hrm — see DESIGN.md §2.
+on TPU — produces shardings with an explicit ``memory_kind`` so XLA
+keeps offloaded tensors in host DRAM; jitted steps move what they use to
+device memory explicitly (``device_operand``).  On the CPU validation
+backend the plan is exercised logically (page store + engine double
+buffer) and its timing modeled by core.cgopipe / core.hrm — see
+DESIGN.md §2.
 """
 from __future__ import annotations
 
@@ -15,27 +16,27 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.policy import Policy, kv_bytes_per_token_layer, model_bytes
 
 
-def backend_memory_kinds() -> List[str]:
-    try:
-        dev = jax.devices()[0]
-        return [m.kind for m in dev.addressable_memories()]
-    except Exception:
-        return []
-
-
 def supports_host_offload() -> bool:
-    return "pinned_host" in backend_memory_kinds()
+    """Whether the serving engine keeps its host tier in pinned_host
+    memory: chosen by platform, not by the listed memory kinds.  On TPU
+    the tier is required (``_make_pinned_sharding`` raises when the space
+    is missing).  Other backends have none the engine can use — jax's CPU
+    backend lists ``pinned_host`` but can neither run the placement
+    annotations nor mix memory spaces in one op — so there the host tier
+    is plain numpy / device arrays."""
+    return jax.devices()[0].platform == "tpu"
 
 
 class HostOffloadFallbackWarning(UserWarning):
-    """The backend has no addressable pinned_host memory space: host-tier
-    stores fall back to default placement (pageable numpy / device)."""
+    """The backend has no pinned_host tier the engine can use: host-tier
+    stores use default placement (pageable numpy / device arrays)."""
 
 
 _warned_no_pinned = False
@@ -53,19 +54,25 @@ def reset_host_probe() -> None:
 def _make_pinned_sharding() -> jax.sharding.Sharding:
     """Single-device sharding in the pinned_host memory space (split out
     so tests can monkeypatch it with a plain CPU sharding and drive the
-    pinned code paths on backends without the memory space)."""
-    return jax.sharding.SingleDeviceSharding(jax.devices()[0],
-                                             memory_kind="pinned_host")
+    pinned code paths on backends without the memory space).  A missing
+    space is an error: on TPU the host tier must not silently become a
+    pageable one."""
+    dev = jax.devices()[0]
+    kinds = [m.kind for m in dev.addressable_memories()]
+    if "pinned_host" not in kinds:
+        raise RuntimeError(f"{dev.device_kind} exposes no pinned_host memory "
+                           f"space (kinds: {kinds})")
+    return jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
 
 
 def pinned_host_sharding(*, warn: bool = True, faults=None
                          ) -> Optional[jax.sharding.Sharding]:
-    """Sharding for host-tier staging buffers, or None when the backend
-    has no pinned_host space (one structured warning per process, until
-    ``reset_host_probe``).  ``faults`` is an optional
-    runtime.faults.FaultInjector: the "host_alloc" site models a failed
-    pinned-host allocation (raises HostMemoryError) — the caller falls
-    back to the pageable tier and may re-probe on ladder promotion."""
+    """Sharding for host-tier staging buffers, or None off TPU (one
+    structured warning per process, until ``reset_host_probe``).
+    ``faults`` is an optional runtime.faults.FaultInjector: the
+    "host_alloc" site models a failed pinned-host allocation (raises
+    HostMemoryError) — the caller falls back to the pageable tier and may
+    re-probe on ladder promotion."""
     global _warned_no_pinned
     if faults is not None:
         faults.raise_for("host_alloc")
@@ -74,26 +81,40 @@ def pinned_host_sharding(*, warn: bool = True, faults=None
     if warn and not _warned_no_pinned:
         _warned_no_pinned = True
         warnings.warn(
-            "backend %r exposes no pinned_host memory space "
-            "(kinds: %s) — host-tier KV blocks and weight pages use "
-            "default placement; H2D transfers will be pageable-rate"
-            % (jax.default_backend(), backend_memory_kinds()),
+            "backend %r has no pinned_host memory space the host tier can "
+            "use — host-tier KV blocks and weight pages use default "
+            "placement" % jax.default_backend(),
             HostOffloadFallbackWarning, stacklevel=2)
     return None
 
 
 def pinned_put(x):
     """Place an array in pinned host memory when available; otherwise
-    return it unchanged (default placement, post-warning)."""
+    return it as a jax array in default placement (post-warning)."""
     s = pinned_host_sharding()
     if s is None:
-        return x
+        return jnp.asarray(x)
     return jax.device_put(x, s)
 
 
 def to_device(x):
     """Stage a (possibly pinned-host) array into device memory."""
     return jax.device_put(x, jax.devices()[0])
+
+
+def in_host_memory(x) -> bool:
+    """True for a traced operand that lives in host memory (a slice of a
+    pinned_host page store inside a jitted step)."""
+    return jax.typeof(x).memory_space == jax.memory.Space.Host
+
+
+def device_operand(x):
+    """Inside a jitted step: the explicit host→device transfer of a host
+    memory operand before compute uses it (XLA refuses compute that mixes
+    memory spaces); device operands pass through untouched."""
+    if in_host_memory(x):
+        return jax.device_put(x, jax.memory.Space.Device)
+    return x
 
 
 @dataclass
@@ -147,11 +168,3 @@ def plan_from_policy(cfg: ModelConfig, abstract_params, pol: Policy
             host.append(path)
     return PlacementPlan(device, host, kv_on_device=pol.kv_gpu_ratio >= 1.0,
                          w_device_bytes=spent, w_host_bytes=total - spent)
-
-
-def host_sharding(mesh, spec) -> Optional[jax.sharding.NamedSharding]:
-    """NamedSharding pinned to host memory when the backend supports it."""
-    s = jax.sharding.NamedSharding(mesh, spec)
-    if supports_host_offload():
-        return s.with_memory_kind("pinned_host")
-    return s

@@ -29,6 +29,7 @@ Two manifest granularities:
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -44,6 +45,27 @@ class LeafEntry:
     shape: Tuple[int, ...]       # per-layer shape (stack dim removed)
     dtype: str
     offset: int                  # element offset within the layer's flat span
+    perm: Tuple[int, ...] = ()   # axis order the span stores it in (() = as is)
+
+    def restore(self, flat, lead: Tuple[int, ...] = ()):
+        """(*lead, n) flat slice of a span -> (*lead, *shape)."""
+        if not self.perm:
+            return flat.reshape(lead + self.shape)
+        x = flat.reshape(lead + tuple(self.shape[i] for i in self.perm))
+        k = len(lead)
+        inv = [int(i) for i in np.argsort(self.perm)]
+        return jnp.transpose(x, tuple(range(k)) + tuple(k + i for i in inv))
+
+
+def _storage_perm(shape: Tuple[int, ...]) -> Tuple[int, ...]:
+    """Axis order a leaf is stored in inside its span: axes shorter than a
+    sublane tile (8) first, the rest in order.  Rebuilding the leaf is then
+    a reshape into tile-sized trailing dims plus a transpose; a reshape
+    that lands a size-2 axis in a tiled position (the (D, 2, F) gate/up
+    layout) made the TPU compiler stall for minutes on a Mixtral-width
+    expert span."""
+    order = sorted(range(len(shape)), key=lambda i: shape[i] >= 8)
+    return tuple(order) if order != list(range(len(shape))) else ()
 
 
 @dataclass
@@ -69,6 +91,39 @@ def _flatten_with_paths(tree, prefix=()):
     return [(prefix, tree)]
 
 
+def _span_pages(elems: int, page_elems: int, dtype) -> int:
+    """Pages per span: enough for ``elems``, rounded up to a whole TPU
+    sublane tile of rows (8 × 32-bit words: 8 f32, 16 bf16, 32 int8
+    pages) so the store keeps its row-major device layout and a span is
+    a slice of the most major axis only — the one cut a host→device copy
+    may make (a span count off the tile makes the TPU compiler re-lay the
+    store out with the span axis tiled, and the host slice then fails)."""
+    tile = 8 * max(1, 4 // np.dtype(dtype).itemsize)
+    return -(-math.ceil(elems / page_elems) // tile) * tile
+
+
+def _pack_pages(leaves, *, perms, lead: int, elems: int, page_elems: int,
+                dtype) -> np.ndarray:
+    """Flatten every leaf behind its first ``lead`` dims (its per-layer
+    axes in ``perms`` storage order), concatenate, zero-pad to ``elems``
+    and cut into pages: (*lead_dims, pages, page_elems).  Built in host
+    memory with numpy — the packed store is the host tier anyway, so
+    packing never holds a second copy of the weights on device (and a
+    device relayout of a gigabyte-sized (D, 2, F) leaf is a program the
+    TPU compiler takes minutes over)."""
+    shape = tuple(leaves[0].shape[:lead])
+    out = np.zeros(shape + (elems,), dtype)
+    off = 0
+    for leaf, perm in zip(leaves, perms):
+        x = np.asarray(leaf)
+        if perm:
+            x = x.transpose(tuple(range(lead)) + tuple(lead + i for i in perm))
+        n = int(np.prod(x.shape[lead:], dtype=np.int64))
+        out[..., off:off + n] = x.reshape(shape + (n,))
+        off += n
+    return out.reshape(shape + (elems // page_elems, page_elems))
+
+
 def pack_layer_stack(stacked: Dict, page_elems: int = 1 << 20
                      ) -> Tuple[jax.Array, PageManifest]:
     """stacked: pytree whose every leaf has a leading `layers` dim L.
@@ -82,17 +137,16 @@ def pack_layer_stack(stacked: Dict, page_elems: int = 1 << 20
         assert leaf.shape[0] == L, f"stack dim mismatch at {path}"
         per_layer = int(np.prod(leaf.shape[1:])) if leaf.ndim > 1 else 1
         entries.append(LeafEntry(path, tuple(leaf.shape[1:]), str(leaf.dtype),
-                                 offset))
+                                 offset, _storage_perm(leaf.shape[1:])))
         offset += per_layer
-    pages_per_layer = math.ceil(offset / page_elems)
+    pages_per_layer = _span_pages(offset, page_elems, dtype)
     layer_elems = pages_per_layer * page_elems
 
-    flat = jnp.concatenate(
-        [leaf.reshape(L, -1).astype(dtype) for _, leaf in leaves], axis=1)
-    pad = layer_elems - flat.shape[1]
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    pages = flat.reshape(L * pages_per_layer, page_elems)
+    pages = _pack_pages([leaf for _, leaf in leaves],
+                        perms=tuple(e.perm for e in entries), lead=1,
+                        elems=layer_elems, page_elems=page_elems,
+                        dtype=np.dtype(dtype)
+                        ).reshape(L * pages_per_layer, page_elems)
     manifest = PageManifest(page_elems, layer_elems, pages_per_layer, L,
                             entries, str(dtype))
     return pages, manifest
@@ -109,7 +163,7 @@ def fetch_layer(pages: jax.Array, manifest: PageManifest, layer) -> Dict:
     for e in manifest.leaves:
         n = int(np.prod(e.shape)) if e.shape else 1
         leaf = jax.lax.dynamic_slice_in_dim(flat, e.offset, n, axis=0)
-        leaf = leaf.reshape(e.shape) if e.shape else leaf[0]
+        leaf = e.restore(leaf) if e.shape else leaf[0]
         node = out
         for p in e.path[:-1]:
             node = node.setdefault(p, {})
@@ -182,12 +236,12 @@ class SplitManifest:
     experts: Optional[ExpertManifest]
 
 
-def pack_expert_stack(expert_leaves, page_elems: int = 1 << 20
-                      ) -> Tuple[jax.Array, ExpertManifest]:
-    """expert_leaves: [(path, arr (L, E, ...))].  Returns
-    (pages (L, E, pages_per_expert, page_elems), manifest).  Leaf paths in
-    the manifest are stored relative to the ``moe`` subtree so a gathered
-    span unflattens straight into the MoE param dict."""
+def expert_manifest(expert_leaves, page_elems: int = 1 << 20
+                    ) -> ExpertManifest:
+    """The manifest ``pack_expert_stack`` packs by, from the leaves'
+    shapes and dtypes alone (arrays or ``jax.ShapeDtypeStruct``s).  Leaf
+    paths are stored relative to the ``moe`` subtree so a gathered span
+    unflattens straight into the MoE param dict."""
     L, NE = expert_leaves[0][1].shape[:2]
     dtype = expert_leaves[0][1].dtype
     entries: List[LeafEntry] = []
@@ -195,22 +249,24 @@ def pack_expert_stack(expert_leaves, page_elems: int = 1 << 20
     for path, leaf in expert_leaves:
         assert leaf.shape[:2] == (L, NE), f"expert stack mismatch at {path}"
         rel = path[path.index("moe") + 1:]
-        per = int(np.prod(leaf.shape[2:])) if leaf.ndim > 2 else 1
+        per = int(np.prod(leaf.shape[2:])) if len(leaf.shape) > 2 else 1
         entries.append(LeafEntry(rel, tuple(leaf.shape[2:]), str(leaf.dtype),
-                                 offset))
+                                 offset, _storage_perm(leaf.shape[2:])))
         offset += per
-    pages_per_expert = math.ceil(offset / page_elems)
-    expert_elems = pages_per_expert * page_elems
+    pages_per_expert = _span_pages(offset, page_elems, dtype)
+    return ExpertManifest(page_elems, pages_per_expert * page_elems,
+                          pages_per_expert, L, NE, entries, str(dtype))
 
-    flat = jnp.concatenate(
-        [leaf.reshape(L, NE, -1).astype(dtype) for _, leaf in expert_leaves],
-        axis=2)
-    pad = expert_elems - flat.shape[2]
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, 0), (0, pad)))
-    pages = flat.reshape(L, NE, pages_per_expert, page_elems)
-    manifest = ExpertManifest(page_elems, expert_elems, pages_per_expert,
-                              L, NE, entries, str(dtype))
+
+def pack_expert_stack(expert_leaves, page_elems: int = 1 << 20
+                      ) -> Tuple[jax.Array, ExpertManifest]:
+    """expert_leaves: [(path, arr (L, E, ...))].  Returns
+    (pages (L, E, pages_per_expert, page_elems), manifest)."""
+    manifest = expert_manifest(expert_leaves, page_elems)
+    pages = _pack_pages([leaf for _, leaf in expert_leaves],
+                        perms=tuple(e.perm for e in manifest.leaves), lead=2,
+                        elems=manifest.expert_elems, page_elems=page_elems,
+                        dtype=np.dtype(manifest.dtype))
     return pages, manifest
 
 
@@ -224,7 +280,7 @@ def unflatten_expert_span(span: jax.Array, em: ExpertManifest) -> Dict:
     out: Dict = {}
     for e in em.leaves:
         n = int(np.prod(e.shape)) if e.shape else 1
-        leaf = flat[..., e.offset:e.offset + n].reshape(lead + e.shape)
+        leaf = e.restore(flat[..., e.offset:e.offset + n], lead)
         node = out
         for p in e.path[:-1]:
             node = node.setdefault(p, {})
@@ -296,6 +352,25 @@ def pack_block_groups_split(blocks: Dict, page_elems: int = 1 << 20
     return PagedWeights(pages, manifests, epages, emanifests)
 
 
+def page_stores(paged) -> Dict:
+    """The arrays of a packed layout (``PagedWeights`` or the whole-layer
+    ``(pages, manifests)`` pair): what a jitted step takes as an argument.
+    A store closed over by the step instead would be baked into the
+    program as a constant, off its pinned_host placement."""
+    if isinstance(paged, PagedWeights):
+        return {"pages": paged.pages, "expert_pages": paged.expert_pages}
+    return paged[0]
+
+
+def bind_page_stores(paged, stores):
+    """``paged`` with its arrays replaced by ``stores`` (the step's traced
+    argument, as laid out by ``page_stores``); the manifests stay."""
+    if isinstance(paged, PagedWeights):
+        return dataclasses.replace(paged, pages=stores["pages"],
+                                   expert_pages=stores["expert_pages"])
+    return stores, paged[1]
+
+
 def unflatten_span(span: jax.Array, manifest: PageManifest) -> Dict:
     """Rebuild one layer's parameter pytree from its page span
     (pages_per_layer, page_elems) — static offsets, reshape-only (used
@@ -305,7 +380,7 @@ def unflatten_span(span: jax.Array, manifest: PageManifest) -> Dict:
     for e in manifest.leaves:
         n = int(np.prod(e.shape)) if e.shape else 1
         leaf = flat[e.offset:e.offset + n]
-        leaf = leaf.reshape(e.shape) if e.shape else leaf[0]
+        leaf = e.restore(leaf) if e.shape else leaf[0]
         node = out
         for p in e.path[:-1]:
             node = node.setdefault(p, {})

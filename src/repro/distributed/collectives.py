@@ -19,20 +19,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.attention import attention_partials
 
-# shard_map compatibility: jax >= 0.6 exposes jax.shard_map (check_vma);
-# older releases have jax.experimental.shard_map.shard_map (check_rep)
-if hasattr(jax, "shard_map"):
-    def _shard_map(body, *, mesh, in_specs, out_specs):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def _shard_map(body, *, mesh, in_specs, out_specs):
-        return _sm(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
-
-
 def lse_combine(o, m, l, axes):
     """Combine attention partials across mesh `axes`.
     o: (B,H,Dv) f32 unnormalized; m, l: (B,H) f32."""
@@ -60,12 +46,12 @@ def make_seq_sharded_attn(mesh: Mesh, dp_axes: Tuple[str, ...],
         return out.astype(q.dtype)
 
     def fn(q, k, v, valid, *, scale, attn_softcap):
-        sm = _shard_map(
+        sm = jax.shard_map(
             functools.partial(body, scale=scale, attn_softcap=attn_softcap),
             mesh=mesh,
             in_specs=(P(dp, None, None), P(dp, kv_axes, None, None),
                       P(dp, kv_axes, None, None), P(dp, kv_axes)),
-            out_specs=P(dp, None, None))
+            out_specs=P(dp, None, None), check_vma=False)
         return sm(q, k, v, valid)
 
     return fn
@@ -146,10 +132,10 @@ def make_moe_shard_fn(mesh: Mesh, cfg, *, variant: str,
             aux = jax.lax.pmean(aux, all_axes)   # replicated metric
             return out.reshape(b, s, D), aux
 
-        sm = _shard_map(
+        sm = jax.shard_map(
             body3, mesh=mesh,
             in_specs=(p_specs, x_spec),
-            out_specs=(x_spec, P()))
+            out_specs=(x_spec, P()), check_vma=False)
         return sm(p, x3)
 
     return fn

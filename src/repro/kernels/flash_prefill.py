@@ -73,7 +73,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
 def flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
                   attn_softcap: float = 0.0, scale=None, kv_len=None,
                   block_q: int = 256, block_k: int = 256,
-                  interpret: bool = True):
+                  interpret: bool):
     """q: (B,S,H,D); k/v: (B,Skv,Hkv,Dv-compat); kv_len: optional (B,).
     Returns (B,S,H,Dv)."""
     B, S, H, D = q.shape

@@ -49,26 +49,25 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, *rest,
     q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, D)
     k = k_ref[0, 0].astype(jnp.float32)                # (bw, D)
     v = v_ref[0, 0].astype(jnp.float32)                # (bw, Dv)
-    valid = valid_ref[0]                               # (bw,)
+    valid = valid_ref[0] != 0                          # (1, bw)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # (G, bw)
     if quantized:       # fold k_scale per tile: s = (q . k_int) * ks
-        s = s * ks_ref[0, 0][None, :]
+        s = s * ks_ref[0, 0]
     if attn_softcap:
         s = attn_softcap * jnp.tanh(s / attn_softcap)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_s[...]                                  # (G,)
-    m_blk = jnp.max(s, axis=1)
-    m_new = jnp.maximum(m_prev, m_blk)
+    m_prev = m_s[...]                                  # (G, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-    p = jnp.exp(s - m_safe[:, None]) * (s > NEG_INF / 2)
+    p = jnp.exp(s - m_safe) * (s > NEG_INF / 2)
     corr = jnp.where(m_prev <= NEG_INF / 2, 0.0,
                      jnp.exp(m_prev - m_safe))
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1)
+    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     if quantized:       # fold v_scale into p: o = (p * vs) @ v_int
-        p = p * vs_ref[0, 0][None, :]
-    acc[...] = acc[...] * corr[:, None] + jax.lax.dot_general(
+        p = p * vs_ref[0, 0]
+    acc[...] = acc[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())))                # (G, Dv)
     # keep the TRUE running max (NEG_INF while nothing valid yet) so the
     # emitted m matches the single-pass oracle even when an all-invalid
@@ -84,11 +83,15 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, *rest,
 
 def gqa_decode(q, k, v, valid, *, scale: float, attn_softcap: float = 0.0,
                k_scale=None, v_scale=None, block_w: int = 512,
-               interpret: bool = True):
+               interpret: bool):
     """q: (B,H,D); k: (B,W,Hkv,D); v: (B,W,Hkv,Dv); valid: (B,W) bool.
     int8 caches pass k_scale/v_scale (B,W,Hkv) f32 — the dequant runs
     tile-wise in VMEM, never as a materialized f32 ring.
-    Returns (o_unnorm (B,H,Dv) f32, m (B,H) f32, l (B,H) f32)."""
+    Returns (o_unnorm (B,H,Dv) f32, m (B,H) f32, l (B,H) f32).
+
+    TPU tiling: the validity mask (as int32) and the scale rows ride with
+    a unit axis before their minor dim, so every block's last two dims
+    span the array's or are (8, 128)-divisible."""
     B, H, D = q.shape
     _, W, Hkv, Dv = v.shape
     G = H // Hkv
@@ -106,17 +109,18 @@ def gqa_decode(q, k, v, valid, *, scale: float, attn_softcap: float = 0.0,
         pl.BlockSpec((1, 1, G, D), lambda b, h, w: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, block_w, D), lambda b, h, w: (b, h, w, 0)),
         pl.BlockSpec((1, 1, block_w, Dv), lambda b, h, w: (b, h, w, 0)),
-        pl.BlockSpec((1, block_w), lambda b, h, w: (b, w)),
+        pl.BlockSpec((1, 1, block_w), lambda b, h, w: (b, 0, w)),
     ]
-    inputs = [qg, kt, vt, valid]
+    inputs = [qg, kt, vt, valid.astype(jnp.int32).reshape(B, 1, W)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, block_w),
-                                  lambda b, h, w: (b, h, w))] * 2
-        inputs += [jnp.swapaxes(k_scale, 1, 2), jnp.swapaxes(v_scale, 1, 2)]
+        in_specs += [pl.BlockSpec((1, 1, 1, block_w),
+                                  lambda b, h, w: (b, h, 0, w))] * 2
+        inputs += [jnp.swapaxes(k_scale, 1, 2).reshape(B, Hkv, 1, W),
+                   jnp.swapaxes(v_scale, 1, 2).reshape(B, Hkv, 1, W)]
     out_shapes = (
         jax.ShapeDtypeStruct((B, Hkv, G, Dv), jnp.float32),
-        jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
-        jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
+        jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+        jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
     )
     kern = functools.partial(_kernel, scale=scale, attn_softcap=attn_softcap,
                              blocks_w=blocks_w, quantized=quantized)
@@ -126,14 +130,14 @@ def gqa_decode(q, k, v, valid, *, scale: float, attn_softcap: float = 0.0,
         in_specs=in_specs,
         out_specs=(
             pl.BlockSpec((1, 1, G, Dv), lambda b, h, w: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, h, w: (b, h, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, h, w: (b, h, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda b, h, w: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda b, h, w: (b, h, 0, 0)),
         ),
         out_shape=out_shapes,
         scratch_shapes=[
             pltpu.VMEM((G, Dv), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
         ],
         # memory-roof-bound by construction: one K + one V read dominates;
         # the hint keeps XLA's scheduler from mis-costing the dispatch

@@ -40,7 +40,8 @@ def _kernel(x_ref, wi_ref, wo_ref, si_ref, so_ref, o_ref, acc, *,
                             (((1,), (0,)), ((), ())))       # (bc, 2*bf)
     # fused weight-only dequant: per-expert scale applied to the matmul
     # OUTPUT tile — the bf16/int8 weights never materialize dequantized
-    h = h * si_ref[0]
+    e = pl.program_id(0)
+    h = h * si_ref[e]
     bf = wi.shape[2]
     gate, up = h[:, :bf], h[:, bf:]
     if act == "silu":
@@ -49,7 +50,7 @@ def _kernel(x_ref, wi_ref, wo_ref, si_ref, so_ref, o_ref, acc, *,
         g = jax.nn.gelu(gate, approximate=True)
     y = g * up                                # (bc, bf)
     acc[...] += jax.lax.dot_general(y, wo,
-                                    (((1,), (0,)), ((), ()))) * so_ref[0]
+                                    (((1,), (0,)), ((), ()))) * so_ref[e]
 
     @pl.when(f == blocks_f - 1)
     def _fin():
@@ -57,13 +58,14 @@ def _kernel(x_ref, wi_ref, wo_ref, si_ref, so_ref, o_ref, acc, *,
 
 
 def moe_ffn(xbuf, wi, wo, *, wi_scale=None, wo_scale=None, act: str = "silu",
-            block_c: int = 128, block_f: int = 512, interpret: bool = True):
+            block_c: int = 128, block_f: int = 512, interpret: bool):
     """xbuf: (E,C,D); wi: (E,D,2,F); wo: (E,F,D) -> (E,C,D).
 
     wi/wo may be int8 (weight-only quantization): pass per-expert
     wi_scale/wo_scale (E,) f32 and the dequant is fused into the tile
     loop — the paper's §3.3 intensity-raising lever with zero extra HBM
-    traffic.
+    traffic.  The (E,) scale vectors ride whole in SMEM (a rank-1 (1,)
+    VMEM block is not a legal TPU tile).
 
     NOTE on the (D,2,F) layout: the kernel reshapes its (D,2,bf) tile to
     (D, 2*bf) for one MXU matmul; gate rows are h[:, :bf], up rows are
@@ -95,8 +97,8 @@ def moe_ffn(xbuf, wi, wo, *, wi_scale=None, wo_scale=None, act: str = "silu",
             pl.BlockSpec((1, block_c, D), lambda e, c, f: (e, c, 0)),
             pl.BlockSpec((1, D, 2, block_f), lambda e, c, f: (e, 0, 0, f)),
             pl.BlockSpec((1, block_f, D), lambda e, c, f: (e, f, 0)),
-            pl.BlockSpec((1,), lambda e, c, f: (e,)),
-            pl.BlockSpec((1,), lambda e, c, f: (e,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, block_c, D), lambda e, c, f: (e, c, 0)),
         out_shape=jax.ShapeDtypeStruct((E, Cp, D), xbuf.dtype),

@@ -1,13 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Dispatch: on TPU the compiled kernels run natively; elsewhere (this CPU
-container) ``interpret=True`` executes the kernel bodies in Python for
-correctness validation, and callers that want XLA-optimized CPU execution
-use the jnp reference path instead (models pass use_kernels=False by
-default off-TPU).  Every dispatcher shares one ``impl`` contract:
+Dispatch: on TPU the compiled kernels run natively; off TPU the kernel
+bodies run only when asked for explicitly (``impl="interpret"``, the
+Pallas interpreter, for correctness validation), and ``auto`` takes the
+jnp reference path.  Every dispatcher shares one ``impl`` contract:
 
   * ``auto``      — Pallas on TPU, the jnp reference path elsewhere;
-  * ``pallas``    — the kernel, compiled natively (interpreted off-TPU);
+  * ``pallas``    — the kernel, compiled natively; an error off TPU;
   * ``interpret`` — the kernel body under the Pallas interpreter;
   * ``ref``       — the pure-jnp oracle.
 """
@@ -29,10 +28,7 @@ _IMPLS = ("auto", "pallas", "interpret", "ref")
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 # -- measured dense-vs-paged crossover (benchmarks/bench_transfer.py) -------
@@ -40,8 +36,9 @@ def on_tpu() -> bool:
 # The paged kernel gathers mapped_blocks × block_bytes; the dense path reads
 # the whole B × max_seq ring but with simpler addressing.  On real devices
 # there is an occupancy above which dense wins; bench_transfer.py measures
-# it and engines resolve impl='auto' against it at init (host-side — the
-# impl string stays a static jit arg).  Unmeasured -> always-paged on TPU.
+# it, a caller installs it explicitly (``load_paged_crossover``), and
+# engines resolve impl='auto' against it at init (host-side — the impl
+# string stays a static jit arg).  Unmeasured -> always-paged on TPU.
 
 _CROSSOVER: dict = {"occ": None}
 
@@ -83,12 +80,16 @@ def paged_auto_impl(occupancy: float) -> str:
 
 
 def _resolve_impl(impl: str):
-    """The shared on-TPU/interpret dance: returns (use_ref, interpret)."""
+    """The shared impl contract: returns (use_ref, interpret)."""
     if impl not in _IMPLS:
         raise ValueError(f"impl must be one of {_IMPLS}, got {impl!r}")
     tpu = on_tpu()
+    if impl == "pallas" and not tpu:
+        raise ValueError("impl='pallas' compiles the kernel for a TPU, but "
+                         f"the backend is {jax.default_backend()!r}; use "
+                         "impl='interpret' to run the kernel body here")
     use_ref = impl == "ref" or (impl == "auto" and not tpu)
-    return use_ref, (impl == "interpret") or not tpu
+    return use_ref, impl == "interpret"
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "attn_softcap",

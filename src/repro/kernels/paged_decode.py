@@ -84,6 +84,38 @@ def _flash_decode_cost(B, H, blocks, bt, D, Dv):
 # GQA (dense or int8 arena)
 # ---------------------------------------------------------------------------
 
+def _merge_masks(pt_ref, pos, b, w, bt, blocks_w):
+    """The fused epilogue's target lanes: (bt, 1) over the K/V tile's
+    token rows and (1, bt) over the slot_pos / scale rows, both true only
+    at the fresh token's ring slot inside its mapped target block."""
+    i = pos % (blocks_w * bt)
+    hit = (w == i // bt) & (pt_ref[b, w] >= 0)
+    rows = (jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0) == i % bt) & hit
+    lanes = (jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1) == i % bt) & hit
+    return rows, lanes
+
+
+def _online_softmax_step(s, v, m_s, l_s, acc, vs=None):
+    """One block of the flash-decode recurrence over scores s (G, bt) and
+    values v (bt, Dv) f32; the (G, 1) max / sumexp and (G, Dv)
+    accumulator live in VMEM scratch across the block grid dimension."""
+    m_prev = m_s[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+    p = jnp.exp(s - m_safe) * (s > NEG_INF / 2)
+    corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
+    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    if vs is not None:
+        p = p * vs
+    acc[...] = acc[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())))                        # (G, Dv)
+    # keep the TRUE running max (NEG_INF while nothing valid yet): an
+    # all-invalid early block must not clamp the max to 0, or a later
+    # block with a negative true max would report m = 0 instead of the
+    # oracle's max
+    m_s[...] = m_new
+
+
 def _gqa_kernel(pt_ref, pos_ref,                     # scalar prefetch (SMEM)
                 q_ref, k_ref, v_ref, *rest,
                 scale: float, attn_softcap: float, window: int,
@@ -106,29 +138,25 @@ def _gqa_kernel(pt_ref, pos_ref,                     # scalar prefetch (SMEM)
         l_s[...] = jnp.zeros_like(l_s)
 
     pos = pos_ref[b]
-    sp = sp_ref[0]                                   # (bt,) this block's ring
+    sp = sp_ref[0]                                   # (1, bt) block's ring
     k = k_ref[0, 0]                                  # (bt, D) arena dtype
     v = v_ref[0, 0]                                  # (bt, Dv)
     if quantized:
-        ks = ks_ref[0, 0]                            # (bt,)
+        ks = ks_ref[0, 0]                            # (1, bt)
         vs = vs_ref[0, 0]
     if fused:
         # merge the fresh token into its target block's tile in-register:
         # the tile then equals the post-write_decode_paged block exactly
         # (k_new is pre-cast to the arena dtype), so attention over the
         # un-written arena is bit-identical to write-then-attend
-        bt = sp.shape[0]
-        i = pos % (blocks_w * bt)
-        hit = (w == i // bt) & (pt_ref[b, w] >= 0)
-        sel = (jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
-               == i % bt) & hit                      # (bt, 1)
-        k = jnp.where(sel, kn_ref[0, 0][None, :], k)
-        v = jnp.where(sel, vn_ref[0, 0][None, :], v)
-        sp = jnp.where(sel[:, 0], pos, sp)
+        rows, lanes = _merge_masks(pt_ref, pos, b, w, k.shape[0], blocks_w)
+        k = jnp.where(rows, kn_ref[0, 0], k)         # kn: (1, D)
+        v = jnp.where(rows, vn_ref[0, 0], v)
+        sp = jnp.where(lanes, pos, sp)
         if quantized:
-            ks = jnp.where(sel[:, 0], kns_ref[0, 0], ks)
-            vs = jnp.where(sel[:, 0], vns_ref[0, 0], vs)
-    valid = (pt_ref[b, w] >= 0) & (sp >= 0) & (sp <= pos)
+            ks = jnp.where(lanes, kns_ref[0, 0], ks)  # kns: (1, 1)
+            vs = jnp.where(lanes, vns_ref[0, 0], vs)
+    valid = (sp >= 0) & (sp <= pos) & (pt_ref[b, w] >= 0)
     if window:
         valid &= sp > pos - window
 
@@ -136,26 +164,12 @@ def _gqa_kernel(pt_ref, pos_ref,                     # scalar prefetch (SMEM)
     s = jax.lax.dot_general(q, k.astype(jnp.float32),
                             (((1,), (1,)), ((), ())))         # (G, bt)
     if quantized:
-        s = s * ks[None, :]
+        s = s * ks
     if attn_softcap:
         s = attn_softcap * jnp.tanh(s / attn_softcap)
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-    p = jnp.exp(s - m_safe[:, None]) * (s > NEG_INF / 2)
-    corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1)
-    if quantized:
-        p = p * vs[None, :]
-    acc[...] = acc[...] * corr[:, None] + jax.lax.dot_general(
-        p, v.astype(jnp.float32), (((1,), (0,)), ((), ())))   # (G, Dv)
-    # keep the TRUE running max (NEG_INF while nothing valid yet): an
-    # all-invalid early block must not clamp the max to 0, or a later
-    # block with a negative true max would report m = 0 instead of the
-    # oracle's max
-    m_s[...] = m_new
+    s = jnp.where(valid, s, NEG_INF)
+    _online_softmax_step(s, v.astype(jnp.float32), m_s, l_s, acc,
+                         vs if quantized else None)
 
     @pl.when(w == blocks_w - 1)
     def _fin():
@@ -169,7 +183,7 @@ def paged_gqa_decode(q, k, v, slot_pos, page_table, pos, *, scale: float,
                      k_scale=None, v_scale=None,
                      k_new=None, v_new=None,
                      k_scale_new=None, v_scale_new=None,
-                     interpret: bool = True):
+                     interpret: bool):
     """q: (B,H,D); k/v: (Hkv, NB, bt, D*) head-major block arena (last
     block = trash, never read); slot_pos: (NB, bt) int32; page_table:
     (B, MB) int32 (-1 = unmapped); pos: (B,) int32 query positions.  int8
@@ -177,9 +191,14 @@ def paged_gqa_decode(q, k, v, slot_pos, page_table, pos, *, scale: float,
     epilogue passes the fresh token k_new/v_new (B, Hkv, D*) — already in
     the arena dtype — (+ k_scale_new/v_scale_new (B, Hkv) for int8); it is
     merged into its target block's tile in-register.  Returns partials
-    (o_unnorm (B,H,Dv) f32, m (B,H) f32, l (B,H) f32)."""
+    (o_unnorm (B,H,Dv) f32, m (B,H) f32, l (B,H) f32).
+
+    TPU tiling: every block's last two dims must be (8, 128)-divisible or
+    span the array's, so the per-block rows (slot_pos, scales, the fresh
+    token) ride with a unit axis inserted before their minor dim and the
+    (max, sumexp) partials come back as (B, Hkv, G, 1)."""
     B, H, D = q.shape
-    Hkv, _, bt, Dv = v.shape
+    Hkv, NB, bt, Dv = v.shape
     MB = page_table.shape[1]
     G = H // Hkv
     qg = q.reshape(B, Hkv, G, D)
@@ -196,14 +215,8 @@ def paged_gqa_decode(q, k, v, slot_pos, page_table, pos, *, scale: float,
         # block at the arena's end is a scatter-only target)
         return (h, jnp.maximum(pt[b, w], 0), 0, 0)
 
-    def idx_scale(b, h, w, pt, ps):
-        return (h, jnp.maximum(pt[b, w], 0), 0)
-
     def idx_sp(b, h, w, pt, ps):
-        return (jnp.maximum(pt[b, w], 0), 0)
-
-    def idx_new(b, h, w, pt, ps):
-        return (b, h, 0)
+        return (jnp.maximum(pt[b, w], 0), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, G, D), idx_q),
@@ -212,19 +225,19 @@ def paged_gqa_decode(q, k, v, slot_pos, page_table, pos, *, scale: float,
     ]
     inputs = [qg, k, v]
     if fused:
-        in_specs += [pl.BlockSpec((1, 1, D), idx_new),
-                     pl.BlockSpec((1, 1, Dv), idx_new)]
-        inputs += [k_new, v_new]
+        in_specs += [pl.BlockSpec((1, 1, 1, D), idx_q),
+                     pl.BlockSpec((1, 1, 1, Dv), idx_q)]
+        inputs += [k_new.reshape(B, Hkv, 1, D), v_new.reshape(B, Hkv, 1, Dv)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, bt), idx_scale),
-                     pl.BlockSpec((1, 1, bt), idx_scale)]
-        inputs += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, 1, 1, bt), idx_blk)] * 2
+        inputs += [k_scale.reshape(Hkv, NB, 1, bt),
+                   v_scale.reshape(Hkv, NB, 1, bt)]
         if fused:
-            in_specs += [pl.BlockSpec((1, 1), lambda b, h, w, pt, ps: (b, h)),
-                         pl.BlockSpec((1, 1), lambda b, h, w, pt, ps: (b, h))]
-            inputs += [k_scale_new, v_scale_new]
-    in_specs.append(pl.BlockSpec((1, bt), idx_sp))
-    inputs.append(slot_pos)
+            in_specs += [pl.BlockSpec((1, 1, 1, 1), idx_q)] * 2
+            inputs += [k_scale_new.reshape(B, Hkv, 1, 1),
+                       v_scale_new.reshape(B, Hkv, 1, 1)]
+    in_specs.append(pl.BlockSpec((1, 1, bt), idx_sp))
+    inputs.append(slot_pos.reshape(NB, 1, bt))
 
     kern = functools.partial(_gqa_kernel, scale=scale,
                              attn_softcap=attn_softcap, window=window,
@@ -237,19 +250,19 @@ def paged_gqa_decode(q, k, v, slot_pos, page_table, pos, *, scale: float,
             in_specs=in_specs,
             out_specs=(
                 pl.BlockSpec((1, 1, G, Dv), idx_q),
-                pl.BlockSpec((1, 1, G), lambda b, h, w, pt, ps: (b, h, 0)),
-                pl.BlockSpec((1, 1, G), lambda b, h, w, pt, ps: (b, h, 0)),
+                pl.BlockSpec((1, 1, G, 1), idx_q),
+                pl.BlockSpec((1, 1, G, 1), idx_q),
             ),
             scratch_shapes=[
                 pltpu.VMEM((G, Dv), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
             ],
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, Hkv, G, Dv), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
         ),
         cost_estimate=_flash_decode_cost(B, H, MB, bt, D, Dv),
         interpret=interpret,
@@ -262,8 +275,8 @@ def paged_gqa_decode(q, k, v, slot_pos, page_table, pos, *, scale: float,
 # ---------------------------------------------------------------------------
 
 def _mla_kernel(pt_ref, pos_ref,
-                q_ref, ckv_ref, kr_ref, *rest,
-                scale: float, lat: int, blocks_w: int, fused: bool):
+                ql_ref, qr_ref, ckv_ref, kr_ref, *rest,
+                scale: float, blocks_w: int, fused: bool):
     rest = list(rest)
     cn_ref = rn_ref = None
     if fused:
@@ -278,39 +291,27 @@ def _mla_kernel(pt_ref, pos_ref,
         l_s[...] = jnp.zeros_like(l_s)
 
     pos = pos_ref[b]
-    sp = sp_ref[0]
+    sp = sp_ref[0]                                   # (1, bt)
     ckv = ckv_ref[0]                                 # (bt, lat) arena dtype
     kr = kr_ref[0]                                   # (bt, dr)
     if fused:
         # in-register merge of the fresh latent — see the GQA kernel note
-        bt = sp.shape[0]
-        i = pos % (blocks_w * bt)
-        hit = (w == i // bt) & (pt_ref[b, w] >= 0)
-        sel = (jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
-               == i % bt) & hit                      # (bt, 1)
-        ckv = jnp.where(sel, cn_ref[0][None, :], ckv)
-        kr = jnp.where(sel, rn_ref[0][None, :], kr)
-        sp = jnp.where(sel[:, 0], pos, sp)
-    valid = (pt_ref[b, w] >= 0) & (sp >= 0) & (sp <= pos)
+        rows, lanes = _merge_masks(pt_ref, pos, b, w, ckv.shape[0], blocks_w)
+        ckv = jnp.where(rows, cn_ref[0], ckv)        # cn: (1, lat)
+        kr = jnp.where(rows, rn_ref[0], kr)
+        sp = jnp.where(lanes, pos, sp)
+    valid = (sp >= 0) & (sp <= pos) & (pt_ref[b, w] >= 0)
 
-    q = q_ref[0].astype(jnp.float32) * scale         # (H, lat + dr)
+    ql = ql_ref[0].astype(jnp.float32) * scale       # (H, lat)
+    qr = qr_ref[0].astype(jnp.float32) * scale       # (H, dr)
     ckv = ckv.astype(jnp.float32)
-    kr = kr.astype(jnp.float32)
     # score against concat(ckv, kr) without building the concat: two
     # partial dots over the latent and rope halves
-    s = jax.lax.dot_general(q[:, :lat], ckv, (((1,), (1,)), ((), ()))) \
-        + jax.lax.dot_general(q[:, lat:], kr, (((1,), (1,)), ((), ())))
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-    p = jnp.exp(s - m_safe[:, None]) * (s > NEG_INF / 2)
-    corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_safe))
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1)
-    acc[...] = acc[...] * corr[:, None] + jax.lax.dot_general(
-        p, ckv, (((1,), (0,)), ((), ())))            # (H, lat)
-    m_s[...] = m_new          # true running max; see the GQA kernel note
+    s = jax.lax.dot_general(ql, ckv, (((1,), (1,)), ((), ()))) \
+        + jax.lax.dot_general(qr, kr.astype(jnp.float32),
+                              (((1,), (1,)), ((), ())))
+    s = jnp.where(valid, s, NEG_INF)
+    _online_softmax_step(s, ckv, m_s, l_s, acc)
 
     @pl.when(w == blocks_w - 1)
     def _fin():
@@ -321,41 +322,45 @@ def _mla_kernel(pt_ref, pos_ref,
 
 def paged_mla_decode(qcat, ckv, kr, slot_pos, page_table, pos, *,
                      scale: float, lat: int,
-                     ckv_new=None, kr_new=None, interpret: bool = True):
+                     ckv_new=None, kr_new=None, interpret: bool):
     """Absorbed MLA decode over the latent block arena.  qcat:
     (B, H, lat + dr) — absorbed latent queries ++ rope queries; ckv:
     (NB, bt, lat); kr: (NB, bt, dr); slot_pos: (NB, bt); page_table:
     (B, MB); pos: (B,).  The fused decode-write epilogue passes the fresh
     latents ckv_new (B, lat) / kr_new (B, dr) in the arena dtype.  The
     attended value is the latent itself, so the partials come back as
-    (o_unnorm (B,H,lat) f32, m, l)."""
+    (o_unnorm (B,H,lat) f32, m, l).  The latent and rope query halves
+    enter as separate blocks, so no lane slice is taken in-kernel."""
     B, H, _ = qcat.shape
-    _, bt, _ = ckv.shape
+    NB, bt, _ = ckv.shape
     dr = kr.shape[-1]
     MB = page_table.shape[1]
     fused = ckv_new is not None
     page_table = page_table.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
 
-    def idx_blk2(b, w, pt, ps):
+    def idx_row(b, w, pt, ps):
+        return (b, 0, 0)
+
+    def idx_blk(b, w, pt, ps):
         return (jnp.maximum(pt[b, w], 0), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, H, lat + dr), lambda b, w, pt, ps: (b, 0, 0)),
-        pl.BlockSpec((1, bt, lat), idx_blk2),
-        pl.BlockSpec((1, bt, dr), idx_blk2),
+        pl.BlockSpec((1, H, lat), idx_row),
+        pl.BlockSpec((1, H, dr), idx_row),
+        pl.BlockSpec((1, bt, lat), idx_blk),
+        pl.BlockSpec((1, bt, dr), idx_blk),
     ]
-    inputs = [qcat, ckv, kr]
+    inputs = [qcat[..., :lat], qcat[..., lat:], ckv, kr]
     if fused:
-        in_specs += [pl.BlockSpec((1, lat), lambda b, w, pt, ps: (b, 0)),
-                     pl.BlockSpec((1, dr), lambda b, w, pt, ps: (b, 0))]
-        inputs += [ckv_new, kr_new]
-    in_specs.append(pl.BlockSpec(
-        (1, bt), lambda b, w, pt, ps: (jnp.maximum(pt[b, w], 0), 0)))
-    inputs.append(slot_pos)
+        in_specs += [pl.BlockSpec((1, 1, lat), idx_row),
+                     pl.BlockSpec((1, 1, dr), idx_row)]
+        inputs += [ckv_new.reshape(B, 1, lat), kr_new.reshape(B, 1, dr)]
+    in_specs.append(pl.BlockSpec((1, 1, bt), idx_blk))
+    inputs.append(slot_pos.reshape(NB, 1, bt))
 
-    kern = functools.partial(_mla_kernel, scale=scale, lat=lat,
-                             blocks_w=MB, fused=fused)
+    kern = functools.partial(_mla_kernel, scale=scale, blocks_w=MB,
+                             fused=fused)
     o, m, l = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -363,22 +368,22 @@ def paged_mla_decode(qcat, ckv, kr, slot_pos, page_table, pos, *,
             grid=(B, MB),
             in_specs=in_specs,
             out_specs=(
-                pl.BlockSpec((1, H, lat), lambda b, w, pt, ps: (b, 0, 0)),
-                pl.BlockSpec((1, H), lambda b, w, pt, ps: (b, 0)),
-                pl.BlockSpec((1, H), lambda b, w, pt, ps: (b, 0)),
+                pl.BlockSpec((1, H, lat), idx_row),
+                pl.BlockSpec((1, H, 1), idx_row),
+                pl.BlockSpec((1, H, 1), idx_row),
             ),
             scratch_shapes=[
                 pltpu.VMEM((H, lat), jnp.float32),
-                pltpu.VMEM((H,), jnp.float32),
-                pltpu.VMEM((H,), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
             ],
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, H, lat), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
         ),
         cost_estimate=_flash_decode_cost(B, H, MB, bt, lat + dr, lat),
         interpret=interpret,
     )(page_table, pos, *inputs)
-    return o, m, l
+    return o, m.reshape(B, H), l.reshape(B, H)

@@ -11,10 +11,17 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    # Auto axes: the sharding plans place arrays through NamedShardings
+    # and let the compiler propagate (jax.make_mesh defaults to Explicit)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(model: int = 2, data: int = 2, pod: int = 0
@@ -22,5 +29,5 @@ def make_debug_mesh(model: int = 2, data: int = 2, pod: int = 0
     """Small mesh over however many (possibly fake) devices exist — used by
     multi-device unit tests run with XLA_FLAGS host-device overrides."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

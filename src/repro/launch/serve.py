@@ -31,9 +31,11 @@ def main():
     import jax
     from repro.configs import get_config
     from repro.core import hrm, policy as pol
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.params import init_params
     from repro.serving.engine import Engine, EngineConfig
 
+    enable_compile_cache()
     cfg_full = get_config(args.arch)
     # HRM policy advice is computed for the FULL model on the target hw
     hw = hrm.preset(args.hw)

@@ -55,22 +55,39 @@ class _ExpertCtx:
 
     def make_fetch(self, layer):
         """Bind the traced layer index: fetch(sel (A,)) gathers the
-        activated experts' spans — resident spans read in place from the
-        pool, misses stream from the host store (on TPU the store lives in
-        pinned host memory, so this gather IS the H2D transfer) — and
-        rebuilds the compacted (A, ...) expert params."""
+        activated experts' spans — a resident span is read in place from
+        the pool, a miss comes from the host store — and rebuilds the
+        compacted (A, ...) expert params.  Each missed span is sliced
+        from the store on its own and moved to device memory explicitly
+        (on TPU the store lives in pinned host memory, so that move IS
+        the H2D transfer; a gather on a host operand does not compile),
+        so only the selected, non-resident spans cross the link, never
+        the layer's full (E, ppe, page_elems) slice."""
+        from repro.core import offload as _offload
         from repro.core import paging as _paging
 
         def fetch(sel):
-            host_span = self.pages[layer][sel]          # (A, ppe, pe)
-            if self.pool is not None:
-                slot = self.resident_map[layer][sel]    # (A,)
-                pool_span = self.pool[jnp.maximum(slot, 0)]
-                span = jnp.where((slot >= 0)[:, None, None],
-                                 pool_span, host_span)
-            else:
-                span = host_span
-            return _paging.unflatten_expert_span(span, self.manifest)
+            L, E, ppe, pe = self.pages.shape
+            # (layer, expert) -> one major index: a host slice may cut
+            # only the most major dimension
+            store = self.pages.reshape(L * E, ppe, pe)
+
+            def from_host(e):
+                return _offload.device_operand(jax.lax.dynamic_index_in_dim(
+                    store, layer * E + e, 0, keepdims=False))
+
+            spans = []
+            for a in range(sel.shape[0]):
+                if self.pool is None:
+                    spans.append(from_host(sel[a]))
+                    continue
+                slot = self.resident_map[layer, sel[a]]
+                spans.append(jax.lax.cond(
+                    slot >= 0,
+                    lambda slot=slot: self.pool[jnp.maximum(slot, 0)],
+                    lambda a=a: from_host(sel[a])))
+            return _paging.unflatten_expert_span(jnp.stack(spans),
+                                                 self.manifest)
 
         return fetch
 
@@ -189,14 +206,25 @@ def _run_group(cfg, specs, stacked_p, x, *, n_steps, positions, cache_group,
     Returns (x, aux, new_cache, new_xattn, expert_counts) where
     expert_counts is {key: (n_steps, E)} (empty without expert_ctx)."""
 
+    manifests = manifests or {}
+    # page stores stay out of the scan's xs: the body slices layer i's
+    # span itself (on the most major axis — a host copy may cut only
+    # that one) and moves a host-resident span to device memory
+    # explicitly before the layer computes on it
+    stores = {k: stacked_p[k] for k in manifests}
+
     def body(carry, xs):
         x, aux = carry
         p_sl, cache_sl, xattn_sl, layer = xs
         if manifests:
+            from repro.core import offload as _offload
             from repro.core import paging as _paging
-            p_sl = {k: (_paging.unflatten_span(v, manifests[k])
-                        if k in manifests else v)
-                    for k, v in p_sl.items()}
+            p_sl = dict(p_sl)
+            for k, store in stores.items():
+                span = jax.lax.dynamic_index_in_dim(store, layer, 0,
+                                                    keepdims=False)
+                p_sl[k] = _paging.unflatten_span(
+                    _offload.device_operand(span), manifests[k])
         has_cache = isinstance(cache_sl, dict)
         has_xc = isinstance(xattn_sl, dict)
         new_caches, new_xs, counts = {}, {}, {}
@@ -229,7 +257,8 @@ def _run_group(cfg, specs, stacked_p, x, *, n_steps, positions, cache_group,
     if policy and policy.remat and mode == "train":
         body = jax.checkpoint(body, prevent_cse=False)
 
-    p_stacked = {f"p{i}": stacked_p[f"p{i}"] for i in range(len(specs))}
+    p_stacked = {f"p{i}": stacked_p[f"p{i}"] for i in range(len(specs))
+                 if f"p{i}" not in manifests}
     cache_stacked = cache_group if cache_group else None
     has_x = any(s.cross_attn for s in specs)
     xattn_stacked = xattn_group if has_x else None

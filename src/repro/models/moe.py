@@ -236,26 +236,17 @@ def moe_grouped(cfg: ModelConfig, p: Dict, x, *, capacity_factor=None,
 # Expert-parallel bodies (to be wrapped in shard_map by distributed.sharding)
 # ---------------------------------------------------------------------------
 
-def _axis_size(name) -> int:
-    """Static mesh-axis size inside shard_map: jax.lax.axis_size on new
-    jax; jax.core.axis_frame(name) (which returns the size) on 0.4.x."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    from jax import core as _core
-    return _core.axis_frame(name)
-
-
 def _combined_axis_index(axis_names):
     idx = jnp.int32(0)
     for a in axis_names:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
 def _combined_axis_size(axis_names):
     m = 1
     for a in axis_names:
-        m *= _axis_size(a)
+        m *= jax.lax.axis_size(a)
     return m
 
 

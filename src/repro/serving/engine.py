@@ -101,6 +101,32 @@ from repro.serving import steps as serve_steps
 from repro.serving.sampling import sample
 from repro.serving.scheduler import Scheduler, ServeRequest, Slot, SlotState
 
+# KV host-tier layout: each spilled block is one row of the tier's most
+# major axis, its payload flattened and zero-padded to whole 128-lane
+# rows.  The TPU compiler copies to and from host memory by whole rows:
+# a block whose minor dims are narrower than a lane tile (slot_pos, the
+# int8 scales) is refused ("Lane slice updating is not supported").
+_LANES = 128
+
+
+def _block_shape(name: str, arena_shape) -> Tuple[int, ...]:
+    ax = kvcache.arena_block_axis(name, stacked=True)
+    return tuple(arena_shape[:ax]) + tuple(arena_shape[ax + 1:])
+
+
+def _lane_rows(shape) -> int:
+    return -(-int(np.prod(shape)) // _LANES)
+
+
+def _to_lane_rows(blk):
+    flat = blk.reshape(-1)
+    flat = jnp.pad(flat, (0, _lane_rows(blk.shape) * _LANES - flat.size))
+    return flat.reshape(-1, _LANES)
+
+
+def _from_lane_rows(rows, shape):
+    return rows.reshape(-1)[:int(np.prod(shape))].reshape(shape)
+
 
 @dataclass
 class EngineConfig:
@@ -295,12 +321,25 @@ class Engine:
                 self._expert_pool[key] = jnp.zeros(
                     (max(1, slots), em.pages_per_expert, em.page_elems),
                     pw.expert_pages[key].dtype)
-            self._pool_write = jax.jit(
-                lambda pool, span, slot: pool.at[slot].set(span),
-                donate_argnums=(0,))
+            # one span per call, sliced from the (pinned-host on TPU)
+            # store and moved to device memory inside the jit
+            def pool_write(pool, pages, l, e, slot):
+                L, E = pages.shape[:2]
+                span = jax.lax.dynamic_index_in_dim(
+                    pages.reshape((L * E,) + pages.shape[2:]), l * E + e,
+                    0, keepdims=False)
+                return pool.at[slot].set(offload.device_operand(span))
+
+            self._pool_write = jax.jit(pool_write, donate_argnums=(0,))
         elif ecfg.paged:
             self.paged_blocks = paging.pack_block_groups(
                 params["blocks"], ecfg.page_elems)
+        if self.paged_blocks is not None:
+            # the steps take the packed stores as their "blocks" argument;
+            # holding no reference to the unpacked blocks lets the caller
+            # free their device copy
+            self.params = {**params,
+                           "blocks": paging.page_stores(self.paged_blocks)}
         # ---------------------------------- block-granular paged KV (r_c)
         # dense-equivalent device bytes of the max_seq-wide slot pool: the
         # baseline every paged-KV report compares against
@@ -342,14 +381,16 @@ class Engine:
                                            block_bytes, faults=self.faults)
 
             def _host_shape(name, a):
-                ax = kvcache.arena_block_axis(name, stacked=True)
-                return a.shape[:ax] + (total,) + a.shape[ax + 1:]
+                # one block per row of the most major axis (the one a host
+                # copy may cut), its payload in whole 128-lane rows
+                return (total, _lane_rows(_block_shape(name, a.shape)),
+                        _LANES)
 
-            # host tier: big enough to hold every spillable block.  When
-            # the backend exposes pinned_host memory the tier lives there
-            # as jax arrays (spills/fetches lower to async DMA against
-            # pinned pages); otherwise it falls back to pageable numpy
-            # (offload emits one structured warning the first time).
+            # host tier: big enough to hold every spillable block.  On TPU
+            # the tier lives in pinned_host memory as jax arrays (spills
+            # and fetches are explicit transfers inside their jits);
+            # elsewhere it is pageable numpy (offload emits one
+            # structured warning the first time).
             try:
                 self._kv_pinned_shd = offload.pinned_host_sharding(
                     faults=self.faults)
@@ -374,8 +415,17 @@ class Engine:
                     key: {name: np.zeros(_host_shape(name, a), a.dtype)
                           for name, a in g.items()}
                     for key, g in self._kv_arena.items()}
+            # spill: one arena block, as the host tier's lane rows
             self._kv_read = jax.jit(
-                lambda a, i, ax: jnp.take(a, i, axis=ax),
+                lambda a, i, ax: _to_lane_rows(jnp.squeeze(
+                    jax.lax.dynamic_index_in_dim(a, i, ax), ax)),
+                static_argnums=(2,))
+            # fetch: one pinned-tier row moved to device memory explicitly,
+            # then the block it holds
+            self._kv_host_read = jax.jit(
+                lambda h, i, shape: _from_lane_rows(offload.device_operand(
+                    jax.lax.dynamic_index_in_dim(h, i, 0, keepdims=False)),
+                    shape),
                 static_argnums=(2,))
             self._kv_write = jax.jit(
                 lambda a, i, v, ax: a.at[(slice(None),) * ax + (i,)].set(v),
@@ -405,14 +455,12 @@ class Engine:
                     int(np.prod(l.shape)) * l.dtype.itemsize
                     for l in jax.tree.leaves(rem_abs))
                 + int(self._kv.dev.nbytes))
-            # resolve impl='auto' against the measured dense-vs-paged
-            # crossover once, host-side (the impl string stays a static
-            # jit arg): the occupancy proxy is the device-resident
-            # fraction of the block pool — at high residency the dense
-            # view's simpler addressing can beat the per-block gather on
-            # real devices (benchmarks/bench_transfer.py measures where)
+            # resolve impl='auto' once, host-side (the impl string stays
+            # a static jit arg), against a dense-vs-paged crossover the
+            # caller installed explicitly (kernel_ops.load_paged_crossover;
+            # none: the kernel on TPU, the ref elsewhere).  The occupancy
+            # proxy is the device-resident fraction of the block pool
             if policy is not None and policy.paged_attn_impl == "auto":
-                kernel_ops.load_paged_crossover()
                 self.policy = policy = dc_replace(
                     policy, paged_attn_impl=kernel_ops.paged_auto_impl(
                         device_blocks / total))
@@ -548,11 +596,10 @@ class Engine:
         # mandatory once residency assigned the slot: the dispatch
         # snapshot says the span is resident, so its bytes must land —
         # injected faults are retried by the transfer engine
-        span = self.paged_blocks.expert_pages[key][l, e]
-
         def _fill():
             self._expert_pool[key] = self._pool_write(
-                self._expert_pool[key], span, jnp.int32(slot))
+                self._expert_pool[key], self.paged_blocks.expert_pages[key],
+                jnp.int32(l), jnp.int32(e), jnp.int32(slot))
 
         self._xfer.run_mandatory("expert_copy", _fill,
                                  nbytes=self.residency[key].span_bytes,
@@ -915,21 +962,22 @@ class Engine:
             h = self._kv_host[key]
             for name in g:
                 ax = kvcache.arena_block_axis(name, stacked=True)
-                blk = self._kv_read(g[name], jnp.int32(pb), ax)
+                rows = self._kv_read(g[name], jnp.int32(pb), ax)
                 if self._kv_pinned:             # D2H into the pinned tier
-                    h[name] = self._kv_host_write(
-                        h[name], jnp.int32(hb), blk, ax)
+                    h[name] = self._kv_host_write(h[name], jnp.int32(hb),
+                                                  rows)
                 else:
-                    h[name][(slice(None),) * ax + (hb,)] = np.asarray(blk)
+                    h[name][hb] = np.asarray(rows)
 
     def _kv_fetch_op(self, hb: int, pb: int) -> None:
         for key, g in self._kv_arena.items():
             h = self._kv_host[key]
             for name in list(g):
                 ax = kvcache.arena_block_axis(name, stacked=True)
-                blk = (self._kv_read(h[name], jnp.int32(hb), ax)
-                       if self._kv_pinned else jnp.asarray(
-                           h[name][(slice(None),) * ax + (hb,)]))
+                shape = _block_shape(name, g[name].shape)
+                blk = (self._kv_host_read(h[name], jnp.int32(hb), shape)
+                       if self._kv_pinned else
+                       _from_lane_rows(jnp.asarray(h[name][hb]), shape))
                 g[name] = self._kv_write(g[name], jnp.int32(pb), blk, ax)
 
     def _kv_exec(self, ops) -> None:
@@ -1148,11 +1196,16 @@ class Engine:
     # ------------------- fault plane: host tier / ladder / watchdog (§10)
     def _build_host_write(self, shd) -> None:
         # (re)built whenever the pinned tier (re)appears: the donated
-        # scatter must carry the tier's sharding so D2H spills land in
-        # pinned pages, not wherever the donation was last placed
-        self._kv_host_write = jax.jit(
-            lambda h, i, v, ax: h.at[(slice(None),) * ax + (i,)].set(v),
-            static_argnums=(3,), donate_argnums=(0,), out_shardings=shd)
+        # update must carry the tier's sharding so D2H spills land in
+        # pinned pages, not wherever the donation was last placed; the
+        # block's lane rows move to host memory explicitly first
+        def write(h, i, v):
+            if offload.in_host_memory(h):
+                v = jax.device_put(v, jax.memory.Space.Host)
+            return jax.lax.dynamic_update_index_in_dim(h, v, i, 0)
+
+        self._kv_host_write = jax.jit(write, donate_argnums=(0,),
+                                      out_shardings=shd)
 
     def _demote_host_tier(self) -> None:
         """Reversible fall-back of the KV host tier from pinned jax
@@ -1164,7 +1217,7 @@ class Engine:
         if self._kv is None or not self._kv_pinned:
             return
         self._kv_host = {
-            key: {name: np.asarray(a) for name, a in g.items()}
+            key: {name: np.array(a) for name, a in g.items()}
             for key, g in self._kv_host.items()}
         self._kv_pinned = False
 
@@ -1396,6 +1449,15 @@ class Engine:
         return int(np.asarray(
             sample(logits, k, temperature=self.ecfg.temperature))[0])
 
+    def _prefill_retires(self, r) -> bool:
+        """Whether the token an admission prefill just appended ends r:
+        its quota is met, or r was re-admitted after preemption and the
+        token is EOS — that prefill stands in for the decode step which,
+        unpreempted, would have sampled the token and stopped the row."""
+        return (len(r.generated) >= r.max_new_tokens
+                or (len(r.generated) > 1
+                    and r.generated[-1] == self.ecfg.eos_id))
+
     def _run_prefill(self, step_fn, *args):
         """Shared prefill wrapper (monolithic fill AND staged chunk)
         absorbing the expert-paged protocol: one fwd pass booked, the
@@ -1440,8 +1502,8 @@ class Engine:
             else:
                 group.cache = self._insert(group.cache, single, slot.row)
             group.last_tok[slot.row] = first
-            if len(r.generated) >= r.max_new_tokens:
-                self._retire_slot(slot)          # quota met at prefill
+            if self._prefill_retires(r):
+                self._retire_slot(slot)
             else:
                 self.scheduler.start_decode(slot)
 
@@ -1502,7 +1564,7 @@ class Engine:
                 self._reset(self._stage_scratch, np.int32(0)))
             self._stage_scratch = None
             self._staged.pop(0)
-            if len(r.generated) >= r.max_new_tokens:
+            if self._prefill_retires(r):
                 self._retire_slot(slot)
             else:
                 self.scheduler.start_decode(slot)

@@ -10,7 +10,9 @@ pytree ({key: (pool, resident_map)} — the device residency snapshot) and
 returns per-layer expert activation counts so the engine's host-side
 residency cache can learn popularity and account H2D traffic.
 ``_expert_granular`` is the single switch deciding which shape a factory
-produces.
+produces.  With paged weights of either granularity the factory holds
+only the manifests: the page stores arrive in ``params["blocks"]`` on
+every call (``paging.page_stores``).
 
 Block-granular paged KV changes no signatures at all: the cache pytree
 the engine composes per dispatch carries the shared block arena plus a
@@ -41,6 +43,15 @@ def _expert_granular(paged_blocks) -> bool:
             and bool(paged_blocks.expert_manifests))
 
 
+def _bound(paged_blocks, params):
+    """The step's paged layout: the factory's manifests over the page
+    stores the call passed in ``params["blocks"]`` (``paging.page_stores``
+    — arguments, so they keep their pinned_host placement)."""
+    if paged_blocks is None:
+        return None
+    return paging.bind_page_stores(paged_blocks, params["blocks"])
+
+
 def make_prefill_step(cfg: ModelConfig,
                       policy: Optional[ExecPolicy] = None) -> Callable:
     """(params, tokens, extras...) -> last-position logits.
@@ -68,7 +79,8 @@ def make_prefill_fill_step(cfg: ModelConfig,
 
     def prefill_step(params, tokens, cache, lens, expert_state=None):
         out = forward(cfg, params, tokens, cache=cache, mode="prefill",
-                      policy=policy, paged_blocks=paged_blocks,
+                      policy=policy,
+                      paged_blocks=_bound(paged_blocks, params),
                       expert_state=expert_state)
         cache = out["cache"]
         cache["pos"] = lens.astype(jnp.int32)
@@ -104,7 +116,8 @@ def make_prefill_chunk(cfg: ModelConfig, policy: Optional[ExecPolicy] = None,
 
     def prefill_chunk(params, tokens, cache, fill_len, expert_state=None):
         out = forward(cfg, params, tokens, cache=cache, mode="chunk_prefill",
-                      policy=policy, paged_blocks=paged_blocks,
+                      policy=policy,
+                      paged_blocks=_bound(paged_blocks, params),
                       fill_len=fill_len, expert_state=expert_state)
         idx = jnp.maximum(fill_len - 1, 0)
         hidden = jnp.take_along_axis(
@@ -172,11 +185,13 @@ def make_decode_chunk(cfg: ModelConfig, policy: Optional[ExecPolicy] = None,
 
     def decode_chunk(params, cache, tok, active, rem, key,
                      expert_state=None):
+        paged = _bound(paged_blocks, params)
+
         def body(carry, _):
             cache, tok, active, rem, key = carry
             pos0 = cache["pos"]
             out = forward(cfg, params, tok, cache=cache, mode="decode",
-                          policy=policy, paged_blocks=paged_blocks,
+                          policy=policy, paged_blocks=paged,
                           expert_state=expert_state,
                           token_groups=token_groups)
             logits = unembed(cfg, params, out["hidden"][:, -1])

@@ -54,7 +54,8 @@ def test_restore_with_shardings(tmp_path):
     """Elastic path: restore with explicit (single-device) shardings."""
     cm = CheckpointManager(tmp_path)
     cm.save(5, _tree())
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     shardings = {"a": sh, "b": {"c": sh}, "step": sh}
     step, tree, _ = cm.restore(shardings=shardings)
@@ -77,7 +78,8 @@ from repro.runtime.checkpoint import CheckpointManager
 
 d = sys.argv[1]
 cm = CheckpointManager(d)
-mesh8 = jax.make_mesh((2, 4), ("data", "model"))
+mesh8 = jax.make_mesh((2, 4), ("data", "model"),
+                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
 x = jax.device_put(jnp.arange(64.).reshape(8, 8),
                    NamedSharding(mesh8, P("data", "model")))
 cm.save(1, {"w": x})

@@ -23,7 +23,8 @@ def test_int8_quantization_error_bound(xs):
 
 
 def _on_mesh(fn, *args):
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import PartitionSpec as P
     return jax.shard_map(fn, mesh=mesh,
                          in_specs=tuple(P() for _ in args),
@@ -64,7 +65,8 @@ def test_error_feedback_converges():
 def test_tree_compression_threads_state(rng):
     g = {"a": jnp.asarray(rng.normal(0, 1, (8,)), jnp.float32),
          "b": {"c": jnp.asarray(rng.normal(0, 1, (4,)), jnp.float32)}}
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import PartitionSpec as P
 
     def body(tree):

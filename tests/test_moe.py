@@ -73,7 +73,8 @@ def test_ep_bodies_match_dense_on_unit_mesh(mixtral_smoke, rng, variant):
     cfg = mixtral_smoke
     p = _moe_params(cfg)
     x = jnp.asarray(rng.normal(0, 0.5, (4, 8, cfg.d_model)), jnp.float32)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from repro.distributed.collectives import make_moe_shard_fn
     fn = make_moe_shard_fn(mesh, cfg, variant=variant, dp_axes=(),
                            expert_axes=("model",), capacity_factor=8.0)

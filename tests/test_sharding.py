@@ -17,7 +17,8 @@ from repro.distributed import sharding as SH
 
 @pytest.fixture(scope="module")
 def mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_spec_divisibility_guard(mesh1):
@@ -25,14 +26,16 @@ def test_spec_divisibility_guard(mesh1):
     # (guard is size-based; on width-1 axes everything divides, so check
     # the rule table instead on a fat fake mesh via spec_for_axes)
     import numpy as np
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rules = {"vocab": "model"}
     spec = SH.spec_for_axes(("vocab", "embed"), (51865, 768), rules, mesh)
     assert spec == P(None) or spec == P("model")  # width-1: trivially ok
 
 
 def test_expert_axis_choice():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     class FakeMesh:
         def __init__(self, sizes):
@@ -86,7 +89,8 @@ ref_step = jax.jit(make_train_step(cfg, opt, None))
 _, _, m_ref = ref_step(params, opt_state, batch)
 
 # 2x4 mesh with the production sharding plan (ep paths exercised)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = SH.make_plan(cfg, shape, mesh, remat=False)
 named = lambda tree: jax.tree.map(
     lambda s: jax.sharding.NamedSharding(mesh, s), tree,
@@ -146,7 +150,8 @@ cache["pos"] = jnp.full((B,), 7, jnp.int32)
 toks = jnp.ones((B, 1), jnp.int32) * 5
 tok_ref, logits_ref, _ = jax.jit(make_serve_step(cfg, None))(
     params, cache, toks)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 shape = dataclasses.replace(get_shape("decode_32k"), global_batch=B,
                             seq_len=S)
 plan = SH.make_plan(cfg, shape, mesh, decode_2d=True)
