@@ -1,0 +1,11 @@
+"""Model FLOPs of the prompt and generated tokens processed in the window
+(bench/flops.py) over the window's seconds and the chip's bf16 peak, in
+percent."""
+from bench import window
+
+
+def read(run):
+    if run.peak is None or not run.model_flops:
+        return None
+    return 100.0 * run.model_flops / window.window_seconds(run.ticks) \
+        / run.peak["bf16_flops_per_s"]
