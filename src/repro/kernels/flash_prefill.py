@@ -126,6 +126,7 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_prefill",
     )(kv_len.astype(jnp.int32), qf, kf, vf)
     o = jnp.swapaxes(o.reshape(B, H, Sp, Dv), 1, 2)
     return o[:, :S] if pq else o

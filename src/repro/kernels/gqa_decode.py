@@ -148,5 +148,6 @@ def gqa_decode(q, k, v, valid, *, scale: float, attn_softcap: float = 0.0,
             transcendentals=B * W * H,
         ),
         interpret=interpret,
+        name="gqa_decode",
     )(*inputs)
     return (o.reshape(B, H, Dv), m.reshape(B, H), l.reshape(B, H))

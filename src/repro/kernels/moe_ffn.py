@@ -104,6 +104,7 @@ def moe_ffn(xbuf, wi, wo, *, wi_scale=None, wo_scale=None, act: str = "silu",
         out_shape=jax.ShapeDtypeStruct((E, Cp, D), xbuf.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, D), jnp.float32)],
         interpret=interpret,
+        name="moe_ffn",
     )(xbuf, wi, wo, wi_scale.astype(jnp.float32),
       wo_scale.astype(jnp.float32))
     return out[:, :C] if pc else out

@@ -266,6 +266,7 @@ def paged_gqa_decode(q, k, v, slot_pos, page_table, pos, *, scale: float,
         ),
         cost_estimate=_flash_decode_cost(B, H, MB, bt, D, Dv),
         interpret=interpret,
+        name="paged_gqa_decode",
     )(page_table, pos, *inputs)
     return o.reshape(B, H, Dv), m.reshape(B, H), l.reshape(B, H)
 
@@ -385,5 +386,6 @@ def paged_mla_decode(qcat, ckv, kr, slot_pos, page_table, pos, *,
         ),
         cost_estimate=_flash_decode_cost(B, H, MB, bt, lat + dr, lat),
         interpret=interpret,
+        name="paged_mla_decode",
     )(page_table, pos, *inputs)
     return o, m.reshape(B, H), l.reshape(B, H)

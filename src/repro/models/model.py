@@ -62,7 +62,11 @@ class _ExpertCtx:
         (on TPU the store lives in pinned host memory, so that move IS
         the H2D transfer; a gather on a host operand does not compile),
         so only the selected, non-resident spans cross the link, never
-        the layer's full (E, ppe, page_elems) slice."""
+        the layer's full (E, ppe, page_elems) slice.
+
+        Returns (params, reads): reads (2,) int32 counts the branches
+        this call executes — [spans read from the host store, spans read
+        from the pool] — over every entry of ``sel``, padding included."""
         from repro.core import offload as _offload
         from repro.core import paging as _paging
 
@@ -76,18 +80,20 @@ class _ExpertCtx:
                 return _offload.device_operand(jax.lax.dynamic_index_in_dim(
                     store, layer * E + e, 0, keepdims=False))
 
-            spans = []
-            for a in range(sel.shape[0]):
-                if self.pool is None:
-                    spans.append(from_host(sel[a]))
-                    continue
-                slot = self.resident_map[layer, sel[a]]
-                spans.append(jax.lax.cond(
-                    slot >= 0,
-                    lambda slot=slot: self.pool[jnp.maximum(slot, 0)],
-                    lambda a=a: from_host(sel[a])))
-            return _paging.unflatten_expert_span(jnp.stack(spans),
-                                                 self.manifest)
+            A = sel.shape[0]
+            if self.pool is None:
+                spans = [from_host(sel[a]) for a in range(A)]
+                n_host = jnp.int32(A)
+            else:
+                slots = self.resident_map[layer, sel]
+                spans = [jax.lax.cond(
+                    slots[a] >= 0,
+                    lambda a=a: self.pool[jnp.maximum(slots[a], 0)],
+                    lambda a=a: from_host(sel[a])) for a in range(A)]
+                n_host = jnp.sum(slots < 0, dtype=jnp.int32)
+            reads = jnp.stack([n_host, A - n_host])
+            return (_paging.unflatten_expert_span(jnp.stack(spans),
+                                                  self.manifest), reads)
 
         return fetch
 
@@ -115,18 +121,19 @@ def block_apply(cfg: ModelConfig, spec: LayerSpec, p: Dict, x, *,
                 xattn_cache: Optional[Dict], policy: Optional[ExecPolicy],
                 causal: bool = True, expert_fetch=None,
                 token_groups: Optional[int] = None):
-    """Returns (x, new_cache, new_xattn_cache, aux_loss, expert_counts).
+    """Returns (x, new_cache, new_xattn_cache, aux_loss, expert).
 
     With ``expert_fetch`` set (expert-granular paged weights), the MoE FFN
     runs the two-phase step: router first, then a gather of only the
-    activated experts' page spans; ``expert_counts`` (E,) reports the
-    routing so the host-side residency cache can learn popularity and
-    account hits/misses.  Otherwise expert_counts is None.
+    activated experts' page spans; ``expert`` is (counts (E,), reads
+    (2,)): the routing, so the host-side residency cache can learn
+    popularity and account hits/misses, and the spans the fetch read
+    from the host store and from the pool.  Otherwise expert is None.
 
     token_groups=G (module-based batching): the batch concatenates G
     rotation groups.  Attention/norms are per-row so they are untouched;
     the MoE FFN stages the G groups' routed tokens into one cross-group
-    buffer so each expert span is read once per window, and expert_counts
+    buffer so each expert span is read once per window, and counts
     becomes (G, E)."""
     aux = jnp.float32(0.0)
     ecounts = None
@@ -138,11 +145,12 @@ def block_apply(cfg: ModelConfig, spec: LayerSpec, p: Dict, x, *,
         x = x + y
     else:
         h = apply_norm(cfg, p.get("attn_norm", {}), x)
-        y, new_cache = attn_forward(
-            cfg, spec, p["attn"], h, positions, cache=cache, mode=mode,
-            pos=pos, sharded_fn=policy.attn_fn if policy else None,
-            paged_impl=policy.paged_attn_impl if policy else "auto",
-            **({} if causal else {"causal": False}))
+        with jax.named_scope("attention"):
+            y, new_cache = attn_forward(
+                cfg, spec, p["attn"], h, positions, cache=cache, mode=mode,
+                pos=pos, sharded_fn=policy.attn_fn if policy else None,
+                paged_impl=policy.paged_attn_impl if policy else "auto",
+                **({} if causal else {"causal": False}))
         if cfg.post_block_norm:
             y = apply_norm(cfg, p["post_attn_norm"], y)
         x = x + y
@@ -169,9 +177,10 @@ def block_apply(cfg: ModelConfig, spec: LayerSpec, p: Dict, x, *,
         h = apply_norm(cfg, p.get("ffn_norm", {}), x)
         if spec.moe:
             if expert_fetch is not None:
-                y, aux, ecounts = moe_apply_paged(cfg, p["moe"], h,
-                                                  expert_fetch, policy,
-                                                  token_groups=token_groups)
+                y, aux, counts, reads = moe_apply_paged(
+                    cfg, p["moe"], h, expert_fetch, policy,
+                    token_groups=token_groups)
+                ecounts = (counts, reads)
             else:
                 y, aux = moe_apply(cfg, p["moe"], h, policy,
                                    token_groups=token_groups)
@@ -203,8 +212,9 @@ def _run_group(cfg, specs, stacked_p, x, *, n_steps, positions, cache_group,
     router-gated per layer (two-phase step); the scan then also stacks
     per-layer expert activation counts for the residency control plane.
 
-    Returns (x, aux, new_cache, new_xattn, expert_counts) where
-    expert_counts is {key: (n_steps, E)} (empty without expert_ctx)."""
+    Returns (x, aux, new_cache, new_xattn, expert) where expert is
+    {key: (counts (n_steps, E), reads (n_steps, 2))} (empty without
+    expert_ctx)."""
 
     manifests = manifests or {}
     # page stores stay out of the scan's xs: the body slices layer i's
@@ -334,7 +344,10 @@ def forward(cfg: ModelConfig, params, tokens, *, cache=None, mode="train",
     whose map entry is >= 0 are read in place from the device pool,
     the rest stream from the host store.  The result dict gains
     "expert_counts" ({key: (n_steps, E)} tokens-routed counts) so the
-    host residency cache can learn popularity and account traffic."""
+    host residency cache can learn popularity and account traffic, and
+    "expert_reads" ({key: (n_steps, 2)}: per layer, the spans the
+    program read from the host store and from the pool, counted in the
+    fetch's branches, padding entries of the activated set included)."""
     B, S = tokens.shape
     if mode == "decode":
         assert cache is not None
@@ -419,7 +432,8 @@ def forward(cfg: ModelConfig, params, tokens, *, cache=None, mode="train",
     x = apply_norm(cfg, params.get("final_norm", {}), x)
     out = {"hidden": x, "cache": new_cache, "aux_loss": aux_total}
     if expert_ctx is not None:
-        out["expert_counts"] = ecounts
+        out["expert_counts"] = {k: c for k, (c, _) in ecounts.items()}
+        out["expert_reads"] = {k: r for k, (_, r) in ecounts.items()}
     return out
 
 

@@ -430,15 +430,18 @@ def _grouped_subset(cfg: ModelConfig, ep: Dict, x, w, idx, index_map,
 def moe_paged(cfg: ModelConfig, p: Dict, x, *, fetch_experts,
               policy=None, max_active: Optional[int] = None,
               token_groups: Optional[int] = None
-              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+              ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Two-phase MoE step for expert-granular paged weights: run the
     router FIRST, then fetch only the activated experts' page spans
-    (``fetch_experts(sel (A,)) -> {wi (A,...), wo (A,...)[, scales]}`` —
-    resident spans read in place from the device pool, misses stream from
-    the host store) and compute on the compacted subset.
+    (``fetch_experts(sel (A,)) -> ({wi (A,...), wo (A,...)[, scales]},
+    reads (2,))`` — resident spans read in place from the device pool,
+    misses stream from the host store) and compute on the compacted
+    subset.
 
     x: (T, D).  Returns (out, aux_loss, counts (E,) int32 — tokens routed
-    to each expert, the residency EWMA's observation).  Numerics match
+    to each expert, the residency EWMA's observation — and the fetch's
+    ``reads``: [host-store reads, pool reads] over all A entries of the
+    activated set, its padding included).  Numerics match
     moe_dense / moe_grouped on the full expert set (skipped experts
     contribute exactly zero there), so greedy transcripts are
     bit-identical to whole-layer streaming.
@@ -455,42 +458,49 @@ def moe_paged(cfg: ModelConfig, p: Dict, x, *, fetch_experts,
     T, D = x.shape
     NE, K = cfg.num_experts, cfg.top_k
     A = max_active if max_active is not None else min(NE, T * K)
-    w, idx, aux = route(cfg, p["router"], x)
-    flat_e = idx.reshape(-1)
-    if token_groups:
-        G = token_groups
-        g_flat = (jnp.arange(T * K) // (K * (T // G))).astype(jnp.int32)
-        counts = jnp.zeros((G, NE), jnp.int32).at[g_flat, flat_e].add(1)
-    else:
-        counts = jnp.zeros((NE,), jnp.int32).at[flat_e].add(1)
-    sel, index_map, n_act = activated_experts(idx, NE, A)
-    ep = fetch_experts(sel)
-    if "wi_scale" in p:
-        # int8 dequant scales live in the shared span (see
-        # paging.EXPERT_LEAF_NAMES): gather the activated experts' scales
-        ep = dict(ep, wi_scale=p["wi_scale"][sel], wo_scale=p["wo_scale"][sel])
-    if policy is not None and policy.moe_impl == "grouped":
-        out = _grouped_subset(cfg, ep, x, w, idx, index_map,
-                              use_kernel=policy.use_kernels,
-                              token_groups=token_groups)
-    else:
-        out = _dense_subset(cfg, ep, x, w, idx, sel, n_act)
-    if cfg.num_shared_experts:
-        out = out + gated_ffn(cfg, p["shared"]["wi"], p["shared"]["wo"], x)
-    return out, aux, counts
+    with jax.named_scope("router"):
+        w, idx, aux = route(cfg, p["router"], x)
+        flat_e = idx.reshape(-1)
+        if token_groups:
+            G = token_groups
+            g_flat = (jnp.arange(T * K) // (K * (T // G))).astype(jnp.int32)
+            counts = jnp.zeros((G, NE), jnp.int32).at[g_flat, flat_e].add(1)
+        else:
+            counts = jnp.zeros((NE,), jnp.int32).at[flat_e].add(1)
+        sel, index_map, n_act = activated_experts(idx, NE, A)
+    with jax.named_scope("expert_fetch"):
+        ep, reads = fetch_experts(sel)
+    with jax.named_scope("moe_ffn"):
+        if "wi_scale" in p:
+            # int8 dequant scales live in the shared span (see
+            # paging.EXPERT_LEAF_NAMES): gather the activated experts'
+            # scales
+            ep = dict(ep, wi_scale=p["wi_scale"][sel],
+                      wo_scale=p["wo_scale"][sel])
+        if policy is not None and policy.moe_impl == "grouped":
+            out = _grouped_subset(cfg, ep, x, w, idx, index_map,
+                                  use_kernel=policy.use_kernels,
+                                  token_groups=token_groups)
+        else:
+            out = _dense_subset(cfg, ep, x, w, idx, sel, n_act)
+        if cfg.num_shared_experts:
+            out = out + gated_ffn(cfg, p["shared"]["wi"], p["shared"]["wo"],
+                                  x)
+    return out, aux, counts, reads
 
 
 def moe_apply_paged(cfg: ModelConfig, p: Dict, x3, fetch_experts,
                     policy=None, token_groups: Optional[int] = None
-                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """(B, S, D) wrapper around moe_paged (the expert-granular analogue of
     moe_apply).  With token_groups, B must be G·ubatch (decode windows)
     so the flat group-major layout holds."""
     B, S, D = x3.shape
-    out, aux, counts = moe_paged(cfg, p, x3.reshape(B * S, D),
-                                 fetch_experts=fetch_experts, policy=policy,
-                                 token_groups=token_groups)
-    return out.reshape(B, S, D), aux, counts
+    out, aux, counts, reads = moe_paged(cfg, p, x3.reshape(B * S, D),
+                                        fetch_experts=fetch_experts,
+                                        policy=policy,
+                                        token_groups=token_groups)
+    return out.reshape(B, S, D), aux, counts, reads
 
 
 def moe_apply(cfg: ModelConfig, p: Dict, x3, policy=None,

@@ -62,7 +62,8 @@ flight, the engine prefetches the expert set group j+1's router gated
 last chunk (the request-level analogue of Algorithm 1's j+2 lookahead),
 drained in ``paging.transfer_plan`` slices so the H2D work rides
 alongside every rotation position's compute.  ``weight_traffic()``
-reports the accounted bytes + hit/miss counters.
+reports the bytes and hit/miss counters booked by the host and the
+expert-span reads counted in the programs.
 
 ``module_batch=True`` decouples the attention and expert phases
 (module-based batching, the MoE-Gen direction): ``module_groups``
@@ -95,6 +96,7 @@ from repro.kernels import ops as kernel_ops
 from repro.models import kvcache
 from repro.models.model import ExecPolicy
 from repro.runtime import faults as faults_mod
+from repro.runtime.spans import span
 from repro.runtime.transfer import TransferEngine
 from repro.runtime.watchdog import Watchdog
 from repro.serving import steps as serve_steps
@@ -126,6 +128,38 @@ def _to_lane_rows(blk):
 
 def _from_lane_rows(rows, shape):
     return rows.reshape(-1)[:int(np.prod(shape))].reshape(shape)
+
+
+# The per-block KV programs, named so that a trace names each one.
+def kv_spill_read(arena, i, ax):
+    """Spill, device half: arena block i as the host tier's lane rows."""
+    return _to_lane_rows(jnp.squeeze(
+        jax.lax.dynamic_index_in_dim(arena, i, ax), ax))
+
+
+def kv_spill_write(host, i, rows):
+    """Spill, host half: the lane rows into tier row i, moved to host
+    memory explicitly first."""
+    if offload.in_host_memory(host):
+        rows = jax.device_put(rows, jax.memory.Space.Host)
+    return jax.lax.dynamic_update_index_in_dim(host, rows, i, 0)
+
+
+def kv_fetch_read(host, i, shape):
+    """Fetch, host half: tier row i moved to device memory explicitly,
+    then the block it holds."""
+    return _from_lane_rows(offload.device_operand(
+        jax.lax.dynamic_index_in_dim(host, i, 0, keepdims=False)), shape)
+
+
+def kv_fetch_write(arena, i, blk, ax):
+    """Fetch, device half: the block written into arena block i."""
+    return arena.at[(slice(None),) * ax + (i,)].set(blk)
+
+
+def kv_clear(slot_pos, idx):
+    """Fresh blocks: clear their slot_pos planes in one scatter."""
+    return slot_pos.at[:, idx].set(-1)
 
 
 @dataclass
@@ -296,6 +330,13 @@ class Engine:
         self._pending_set: set = set()
         self._predictors: Dict[str, residency.GatePredictor] = {}
         self._fwd_passes = 0          # forward passes dispatched (traffic)
+        # weight_traffic() keys counted here rather than booked by
+        # core.residency: host seconds of the weight-paging spans, and
+        # the expert-span reads the programs count in their fetches
+        self._wt = {"host_s": 0.0, "read_spans": 0, "read_bytes": 0,
+                    "pool_reads": 0, "pad_reads": 0}
+        # kv_traffic(): host seconds of the KV spans, per-block programs
+        self._kvt = {"host_s": 0.0, "dispatches": 0}
         if ecfg.expert_paged:
             pw = paging.pack_block_groups_split(params["blocks"],
                                                 ecfg.page_elems)
@@ -415,23 +456,11 @@ class Engine:
                     key: {name: np.zeros(_host_shape(name, a), a.dtype)
                           for name, a in g.items()}
                     for key, g in self._kv_arena.items()}
-            # spill: one arena block, as the host tier's lane rows
-            self._kv_read = jax.jit(
-                lambda a, i, ax: _to_lane_rows(jnp.squeeze(
-                    jax.lax.dynamic_index_in_dim(a, i, ax), ax)),
-                static_argnums=(2,))
-            # fetch: one pinned-tier row moved to device memory explicitly,
-            # then the block it holds
-            self._kv_host_read = jax.jit(
-                lambda h, i, shape: _from_lane_rows(offload.device_operand(
-                    jax.lax.dynamic_index_in_dim(h, i, 0, keepdims=False)),
-                    shape),
-                static_argnums=(2,))
-            self._kv_write = jax.jit(
-                lambda a, i, v, ax: a.at[(slice(None),) * ax + (i,)].set(v),
-                static_argnums=(3,), donate_argnums=(0,))
-            self._kv_clear = jax.jit(lambda sp, idx: sp.at[:, idx].set(-1),
+            self._kv_read = jax.jit(kv_spill_read, static_argnums=(2,))
+            self._kv_host_read = jax.jit(kv_fetch_read, static_argnums=(2,))
+            self._kv_write = jax.jit(kv_fetch_write, static_argnums=(3,),
                                      donate_argnums=(0,))
+            self._kv_clear = jax.jit(kv_clear, donate_argnums=(0,))
             self._kv_pending: List[Tuple[int, int]] = []
             self._kv_pending_set: set = set()
             self._static_gids: List[int] = list(range(ecfg.num_ubs))
@@ -557,10 +586,11 @@ class Engine:
         decode chunks.  Static mode decodes one token per active
         micro-batch and retires whole groups.  Returns True if any work
         was done."""
-        self._ladder_tick()       # safe point: no dispatch in flight
-        if self.ecfg.mode == "static":
-            return self._step_static()
-        return self._step_continuous()
+        with span("engine.step"):
+            self._ladder_tick()       # safe point: no dispatch in flight
+            if self.ecfg.mode == "static":
+                return self._step_static()
+            return self._step_continuous()
 
     def run_until_idle(self, max_steps: int = 10_000) -> Dict[int, List[int]]:
         while self.step() and self.steps < max_steps:
@@ -601,9 +631,10 @@ class Engine:
                 self._expert_pool[key], self.paged_blocks.expert_pages[key],
                 jnp.int32(l), jnp.int32(e), jnp.int32(slot))
 
-        self._xfer.run_mandatory("expert_copy", _fill,
-                                 nbytes=self.residency[key].span_bytes,
-                                 on_hostmem=self._demote_host_tier)
+        nbytes = self.residency[key].span_bytes
+        with span("weights.copy", layer=l, expert=e, bytes=nbytes):
+            self._xfer.run_mandatory("expert_copy", _fill, nbytes=nbytes,
+                                     on_hostmem=self._demote_host_tier)
 
     def _resident_snap(self) -> Dict[str, np.ndarray]:
         """Residency mask at dispatch time — what the jitted call's map
@@ -612,14 +643,15 @@ class Engine:
         return {k: (r.slot_of >= 0).copy()
                 for k, r in self.residency.items()}
 
-    def _account_counts(self, counts, holder=None, snap=None,
-                        holders=None, hidden=None) -> None:
+    def _account_counts(self, counts, reads, snap, holders=(),
+                        window=False, hidden=None) -> None:
         """Book a call's expert activation counts ({key: (..., P, E)}):
         per forward pass, hits/misses against the residency snapshot the
         pass actually read, then demand-admit the missed spans — hottest
-        first, so the miss stream doubles as cache fill.  Updates
-        `holder.pred` with the last pass's gating (the router-ahead
-        prediction for that group's next chunk).
+        first, so the miss stream doubles as cache fill.  Updates the
+        holder's `pred` (``holders[0]``) with the last pass's gating (the
+        router-ahead prediction for that group's next chunk).  The
+        program-counted reads are booked first (``_book_reads``).
 
         ``hidden`` ({key: (L, E) bool}) marks the spans whose prefetch
         landed *while this call was in flight* (captured right after the
@@ -643,20 +675,22 @@ class Engine:
         second pass onward.  This changes only WHEN bytes are charged —
         the computation reads identical weights either way.
 
-        With ``holders`` (a module-batched window) the count arrays carry
-        a group axis ({key: (..., P, G, E)}): each forward pass books ONE
-        per-window union observation (``observe_window`` — an expert span
-        streams at most once per window regardless of how many groups
-        routed to it), and each group's holder gets its own last-pass
-        prediction so router-ahead prefetch stays per group."""
+        With ``window`` (a module-batched window over ``holders``) the
+        count arrays carry a group axis ({key: (..., P, G, E)}): each
+        forward pass books ONE per-window union observation
+        (``observe_window`` — an expert span streams at most once per
+        window regardless of how many groups routed to it), and each
+        group's holder gets its own last-pass prediction so router-ahead
+        prefetch stays per group."""
+        self._book_reads(reads, counts, snap, window)
         for key, arr in counts.items():
             r = self.residency[key]
             r.begin_chunk()          # refresh the demand-evict victim quota
             a = np.asarray(arr)
-            mask = snap[key] if snap is not None else None
+            mask = snap[key]
             hid = hidden.get(key) if hidden is not None else None
             gp = self._predictors.get(key)
-            intra = self.ecfg.intra_pass and mask is not None
+            intra = self.ecfg.intra_pass
             cur = mask.copy() if intra else mask
             want: Dict[Tuple[int, int], bool] = {}
 
@@ -673,7 +707,7 @@ class Engine:
                         # streamed once, staged for the rest of the chunk
                         cur[pair] = True
 
-            if holders is not None:
+            if window:
                 steps = a.reshape(-1, *a.shape[-3:])      # (n_fwd, P, G, E)
                 for si, s in enumerate(steps):
                     per_g = np.moveaxis(s, 1, 0)          # (G, P, E)
@@ -696,12 +730,34 @@ class Engine:
             if r.replicate_frac > 0.0:
                 for l, e, slot in r.update_replicas():
                     self._copy_span(key, l, e, slot)
-            if holder is not None:
-                holder.pred[key] = steps[-1] > 0
-            if holders is not None:
+            if window:
                 last = steps[-1]                          # (P, G, E)
                 for g, h in enumerate(holders):
                     h.pred[key] = last[:, g, :] > 0
+            elif holders:
+                holders[0].pred[key] = steps[-1] > 0
+
+    def _book_reads(self, reads, counts, snap, window) -> None:
+        """Book the expert-span reads a call's program counted in its
+        fetch branches ({key: (..., P, 2)}: host-store and pool reads
+        per forward pass and layer).  Unlike the residency booking, this
+        is what the program moved: under the dispatch snapshot, frozen
+        for the whole call, a missed span is read again at every pass.
+        ``pad_reads`` cross-checks the count from the routing: the host
+        reads that no activated expert asked for, i.e. padding entries
+        of ``moe.activated_experts`` whose expert-0 span was not
+        resident."""
+        for key, arr in reads.items():
+            a = np.asarray(arr).reshape(-1, 2).sum(axis=0)
+            act = np.asarray(counts[key]) > 0
+            if window:
+                act = act.any(axis=-2)            # the window's union
+            host, pool = int(a[0]), int(a[1])
+            asked = int((act & ~snap[key]).sum())
+            self._wt["read_spans"] += host
+            self._wt["read_bytes"] += host * self.residency[key].span_bytes
+            self._wt["pool_reads"] += pool
+            self._wt["pad_reads"] += host - asked
 
     def _next_gids(self, gid) -> List[int]:
         """The rotation group(s) decoding next: gid+1 for a lockstep
@@ -830,32 +886,40 @@ class Engine:
         self._pending = keep + requeued
 
     def weight_traffic(self) -> Dict[str, float]:
-        """Accounted H2D weight traffic (DESIGN.md §2: on this container
-        traffic is modeled, not physically moved).  Whole-layer paging
-        streams every group's full span each forward pass; the
-        expert-granular path streams the shared spans plus the
-        missed/prefetched expert spans booked by core.residency.
+        """Host-to-device weight traffic.  Whole-layer paging streams
+        every group's full span each forward pass; the expert-granular
+        path streams the shared spans plus expert spans.
 
-        Per-phase breakdown (module-based batching observability):
-        ``attn_phase_bytes`` is what the attention phase streams (the
-        shared attention/norm/router spans, once per forward pass — a
-        window's pass serves all its groups), ``expert_phase_bytes`` is
-        the expert-span traffic of the expert phase (misses + prefetch),
-        ``bytes_per_token_amortized`` = total / tokens emitted, and
-        ``module_groups_effective`` is the MEASURED amortization —
-        lockstep-equivalent misses / per-window union misses — so the
-        1/G claim is counter-verified, not inferred."""
+        Booked by the host (core.residency, per call against the
+        dispatch snapshot): ``expert_bytes`` (= ``expert_phase_bytes``),
+        ``hits``, ``misses``, ``prefetches`` and the rest of the
+        hit/miss attribution.  With ``intra_pass`` a missed span is
+        charged once per call, although the call's program reads it at
+        every forward pass.
+
+        Counted in the programs (expert-granular path): ``read_spans``,
+        the fetch branches that read a span from the host store, every
+        pass and padding entry included; ``read_bytes`` = read_spans ×
+        span bytes; ``pool_reads``, the branches that read the device
+        pool; ``pad_reads``, the host reads no activated expert asked
+        for (padding of the activated set; see ``_book_reads``).
+
+        ``host_s``: host seconds in the weight-paging layer's spans
+        (``repro.weights.book`` and ``repro.weights.prefetch``).
+
+        ``bytes_per_token_amortized`` = ``h2d_bytes`` / tokens emitted,
+        and ``module_groups_effective`` is the measured amortization of
+        module batching — lockstep-equivalent misses / per-window union
+        misses."""
         out: Dict[str, float] = {"fwd_passes": self._fwd_passes,
                                  "tokens_out": self.tokens_out,
                                  "module_batch": self._mg > 1,
-                                 "module_groups": self._mg}
+                                 "module_groups": self._mg,
+                                 "host_s": self._wt["host_s"]}
         if self.residency:
             pw = self.paged_blocks
             shared = sum(pw.shared_layer_bytes(k) * pw.manifests[k].num_layers
                          for k in pw.manifests)
-            expert_full = sum(
-                em.span_bytes * em.num_experts * em.num_layers
-                for em in pw.expert_manifests.values())
             c = [r.counters for r in self.residency.values()]
             misses = sum(x.misses for x in c)
             lockstep = sum(x.lockstep_misses for x in c)
@@ -898,14 +962,11 @@ class Engine:
                 miss_stall_bytes_per_layer={
                     k: [int(b) for b in r.miss_stall_bytes]
                     for k, r in self.residency.items()},
-                # what whole-layer streaming would have moved for the
-                # same passes (shared + every expert span every layer)
-                whole_layer_bytes=(shared + expert_full) * self._fwd_passes,
                 module_groups_effective=(lockstep / misses if misses
                                          else float(self._mg)),
+                **self._wt,
             )
             out["h2d_bytes"] = out["shared_bytes"] + out["expert_bytes"]
-            out["attn_phase_bytes"] = out["shared_bytes"]
             out["expert_phase_bytes"] = out["expert_bytes"]
         elif self.ecfg.paged:
             _, manifests = self.paged_blocks
@@ -913,12 +974,10 @@ class Engine:
                 m.pages_per_layer * m.page_elems * m.num_layers
                 * np.dtype(m.dtype).itemsize for m in manifests.values())
             out.update(mode="paged", h2d_bytes=per_pass * self._fwd_passes,
-                       attn_phase_bytes=per_pass * self._fwd_passes,
                        expert_phase_bytes=0,
                        module_groups_effective=float(self._mg))
         else:
-            out.update(mode="resident", h2d_bytes=0, attn_phase_bytes=0,
-                       expert_phase_bytes=0,
+            out.update(mode="resident", h2d_bytes=0, expert_phase_bytes=0,
                        module_groups_effective=float(self._mg))
         out["bytes_per_token_amortized"] = (out["h2d_bytes"]
                                             / max(1, self.tokens_out))
@@ -966,8 +1025,10 @@ class Engine:
                 if self._kv_pinned:             # D2H into the pinned tier
                     h[name] = self._kv_host_write(h[name], jnp.int32(hb),
                                                   rows)
+                    self._kvt["dispatches"] += 1
                 else:
                     h[name][hb] = np.asarray(rows)
+                self._kvt["dispatches"] += 1
 
     def _kv_fetch_op(self, hb: int, pb: int) -> None:
         for key, g in self._kv_arena.items():
@@ -975,10 +1036,13 @@ class Engine:
             for name in list(g):
                 ax = kvcache.arena_block_axis(name, stacked=True)
                 shape = _block_shape(name, g[name].shape)
-                blk = (self._kv_host_read(h[name], jnp.int32(hb), shape)
-                       if self._kv_pinned else
-                       _from_lane_rows(jnp.asarray(h[name][hb]), shape))
+                if self._kv_pinned:
+                    blk = self._kv_host_read(h[name], jnp.int32(hb), shape)
+                    self._kvt["dispatches"] += 1
+                else:
+                    blk = _from_lane_rows(jnp.asarray(h[name][hb]), shape)
                 g[name] = self._kv_write(g[name], jnp.int32(pb), blk, ax)
+                self._kvt["dispatches"] += 1
 
     def _kv_exec(self, ops) -> None:
         """Execute a BlockPool plan in order: ``spill`` copies an arena
@@ -996,14 +1060,18 @@ class Engine:
         for op in ops:
             if op[0] == "spill":
                 _, _s, _lb, pb, hb = op
-                self._xfer.run_mandatory(
-                    "kv_spill", lambda pb=pb, hb=hb: self._kv_spill_op(pb, hb),
-                    nbytes=nb, on_hostmem=self._demote_host_tier)
+                with span("kv.spill", block=pb):
+                    self._xfer.run_mandatory(
+                        "kv_spill",
+                        lambda pb=pb, hb=hb: self._kv_spill_op(pb, hb),
+                        nbytes=nb, on_hostmem=self._demote_host_tier)
             elif op[0] == "fetch":
                 _, _s, _lb, hb, pb = op
-                self._xfer.run_mandatory(
-                    "kv_fetch", lambda hb=hb, pb=pb: self._kv_fetch_op(hb, pb),
-                    nbytes=nb, on_hostmem=self._demote_host_tier)
+                with span("kv.fetch", block=pb):
+                    self._xfer.run_mandatory(
+                        "kv_fetch",
+                        lambda hb=hb, pb=pb: self._kv_fetch_op(hb, pb),
+                        nbytes=nb, on_hostmem=self._demote_host_tier)
             else:                                       # ("alloc", s, lb, pb)
                 fresh.append(op[3])
         if fresh:
@@ -1018,6 +1086,7 @@ class Engine:
             idxj = jnp.asarray(idx)
             for key, g in self._kv_arena.items():
                 g["slot_pos"] = self._kv_clear(g["slot_pos"], idxj)
+                self._kvt["dispatches"] += 1
 
     def _kv_ensure(self, fn):
         """Run a BlockPool ensure closure on a path whose refusal is
@@ -1025,12 +1094,13 @@ class Engine:
         fallbacks follow the call): injected pool exhaustions are
         retried until a genuine answer comes back, so a chaos schedule
         can never trip a floor assert or force a spurious fallback."""
-        while True:
-            ops, ok, nxt = fn()
-            self._kv_exec(ops)
-            if ok or not self._kv.last_refusal_injected:
-                return ops, ok, nxt
-            self._xfer.book_retry("kv_pool")
+        with span("kv.prepare", self._kvt):
+            while True:
+                ops, ok, nxt = fn()
+                self._kv_exec(ops)
+                if ok or not self._kv.last_refusal_injected:
+                    return ops, ok, nxt
+                self._xfer.book_retry("kv_pool")
 
     def _kv_sweep(self) -> None:
         """Release arena/host blocks of any slot that fell back to FREE
@@ -1152,10 +1222,13 @@ class Engine:
     def kv_traffic(self) -> Dict[str, float]:
         """Device-KV accounting: bytes the KV pool actually occupies on
         device vs the dense max_seq-wide equivalent, plus the host-tier
-        stream counters (same modeled-traffic discipline as
-        ``weight_traffic``)."""
+        stream counters booked by core.blockpool.  ``host_s``: host
+        seconds in the KV layer's spans (``repro.kv.prepare`` and
+        ``repro.kv.prefetch``); ``dispatches``: per-block programs
+        launched (spill, fetch and clear)."""
         out: Dict[str, float] = {"tokens_out": self.tokens_out,
-                                 "dense_equiv_bytes": self._kv_dense_bytes}
+                                 "dense_equiv_bytes": self._kv_dense_bytes,
+                                 **self._kvt}
         if self._kv is None:
             out.update(mode="kv_dense",
                        device_kv_bytes=self._kv_dense_bytes,
@@ -1197,14 +1270,8 @@ class Engine:
     def _build_host_write(self, shd) -> None:
         # (re)built whenever the pinned tier (re)appears: the donated
         # update must carry the tier's sharding so D2H spills land in
-        # pinned pages, not wherever the donation was last placed; the
-        # block's lane rows move to host memory explicitly first
-        def write(h, i, v):
-            if offload.in_host_memory(h):
-                v = jax.device_put(v, jax.memory.Space.Host)
-            return jax.lax.dynamic_update_index_in_dim(h, v, i, 0)
-
-        self._kv_host_write = jax.jit(write, donate_argnums=(0,),
+        # pinned pages, not wherever the donation was last placed
+        self._kv_host_write = jax.jit(kv_spill_write, donate_argnums=(0,),
                                       out_shardings=shd)
 
     def _demote_host_tier(self) -> None:
@@ -1312,8 +1379,6 @@ class Engine:
             "host_tier_pinned": bool(getattr(self, "_kv_pinned", False)),
             "module_groups_now": self._mg,
             "predict_suspended": self._degraded_no_predict,
-            "dispatch_slow_steps": (self._watchdog.slow_steps
-                                    if self._watchdog is not None else 0),
         }
         out.update(self._xfer.stats())
         if self._ladder is not None:
@@ -1327,41 +1392,68 @@ class Engine:
                        promotions=0, degradation_events=[])
         return out
 
-    def _decode_group(self, cache, last_tok, active, rem, *, holder=None,
-                      gid: Optional[int] = None):
+    def _decode(self, cache, last_tok, active, rem, *, holders, gid):
         """Run one masked decode chunk; returns (cache, new_last_tok,
         still_active, toks (T,B), emitted (T,B)) as host arrays where
-        relevant.  On the expert-paged path: pins every resident span for
-        the duration of the dispatch (the chunk may read any of them in
+        relevant.  ``gid`` is one rotation group's id (``holders`` = its
+        one holder), or a list of ids for a module-batched window: ONE
+        combined chunk over G groups' rows (G·ubatch, group-major).
+        Attention/norms are per-row, so every row's numerics match its
+        lockstep dispatch bit-for-bit; the MoE layers stage all groups'
+        routed tokens against a single expert-span read per layer step,
+        so the forward-pass counter advances by `chunk` for the WHOLE
+        window — each shared span (and each missed expert span, booked
+        per window by ``observe_window``) is charged once per window:
+        that is the amortization.
+
+        On the expert-paged path: pins every resident span for the
+        duration of the dispatch (the chunk may read any of them in
         place), issues the router-ahead prefetch for the next rotation
-        group while the chunk is in flight, then books the returned
-        activation counts."""
-        self.key, k = jax.random.split(self.key)
-        args = (self.params, cache, jnp.asarray(last_tok[:, None]),
-                jnp.asarray(active), jnp.asarray(rem), k)
-        chunk = self.ecfg.decode_chunk if self.ecfg.mode == "continuous" else 1
-        self._fwd_passes += chunk
-        if self._watchdog is not None:
-            self._watchdog.step_start()
-        if self.residency:
+        group (or window) while the chunk is in flight, then books the
+        returned activation counts and program-counted reads.  Static
+        mode passes ``gid=None`` (no prefetch)."""
+        window = isinstance(gid, list)
+        fn = self._decode_window_fn if window else self._decode_chunk
+        label = "+".join(map(str, gid)) if window else str(gid)
+        with span("engine.decode", gid=label, rows=int(active.sum())):
+            self.key, k = jax.random.split(self.key)
+            args = (self.params, cache, jnp.asarray(last_tok[:, None]),
+                    jnp.asarray(active), jnp.asarray(rem), k)
+            chunk = (self.ecfg.decode_chunk
+                     if self.ecfg.mode == "continuous" else 1)
+            self._fwd_passes += chunk
+            if self._watchdog is not None:
+                self._watchdog.step_start()
+            if not self.residency:
+                with span("engine.dispatch"):
+                    cache, tok, act2, _, toks, emitted = fn(*args)
+                with span("engine.wait"):
+                    res = (cache, np.array(tok)[:, 0], np.asarray(act2),
+                           np.asarray(toks), np.asarray(emitted))
+                self._watchdog_end()
+                return res
             snap = self._resident_snap()
             for r in self.residency.values():
                 r.pin_resident()
-            cache, tok, act2, _, toks, emitted, counts = self._decode_chunk(
-                *args, self._expert_state())
+            with span("engine.dispatch"):
+                cache, tok, act2, _, toks, emitted, counts, reads = fn(
+                    *args, self._expert_state())
             prefetching = (self.ecfg.prefetch and gid is not None
                            and self.groups)
             if prefetching:
-                # in flight: fill free slots for group gid+1's predicted
-                # set (H2D overlaps the dispatched compute), then the
-                # gate predictor's intra-pass lookahead for THIS group's
-                # next chunk (deduped against the router-ahead entries)
-                self._enqueue_prediction(gid)
-                if self._predictors and holder is not None:
-                    self._enqueue_gate_predictions([holder])
-                self._drain_prefetch(gid, retry_refused=True)
-            res = (cache, np.array(tok)[:, 0], np.asarray(act2),
-                   np.asarray(toks), np.asarray(emitted))   # sync
+                # in flight: fill free slots for the next group's
+                # predicted set (H2D overlaps the dispatched compute),
+                # then the gate predictor's intra-pass lookahead for
+                # THESE groups' next chunk (deduped against the
+                # router-ahead entries)
+                with span("weights.prefetch", self._wt):
+                    self._enqueue_prediction(gid)
+                    if self._predictors:
+                        self._enqueue_gate_predictions(holders)
+                    self._drain_prefetch(gid, retry_refused=True)
+            with span("engine.wait"):
+                res = (cache, np.array(tok)[:, 0], np.asarray(act2),
+                       np.asarray(toks), np.asarray(emitted))
             self._watchdog_end()
             # spans that became resident between dispatch and landing:
             # their H2D stream overlapped this chunk's compute, so a
@@ -1372,65 +1464,12 @@ class Engine:
                 r.unpin_all()
             if prefetching:
                 # landed: retry the refused slice, evictions now allowed
-                self._drain_prefetch(gid, retry_refused=False)
-            self._account_counts(counts, holder=holder, snap=snap,
-                                 hidden=hidden)
+                with span("weights.prefetch", self._wt):
+                    self._drain_prefetch(gid, retry_refused=False)
+            with span("weights.book", self._wt):
+                self._account_counts(counts, reads, snap, holders=holders,
+                                     window=window, hidden=hidden)
             return res
-        cache, tok, act2, _, toks, emitted = self._decode_chunk(*args)
-        res = (cache, np.array(tok)[:, 0], np.asarray(act2),
-               np.asarray(toks), np.asarray(emitted))   # sync
-        self._watchdog_end()
-        return res
-
-    def _decode_window(self, cache, last_tok, active, rem, *, holders, gids):
-        """Module-batched analogue of ``_decode_group``: ONE combined
-        masked decode chunk over a window of G rotation groups (G·ubatch
-        rows, group-major).  Attention/norms are per-row so every row's
-        numerics match its lockstep dispatch bit-for-bit; the MoE layers
-        stage all groups' routed tokens against a single expert-span read
-        per layer step.  The forward-pass counter therefore advances by
-        `chunk` for the WHOLE window — each shared span (and each missed
-        expert span, booked per-window by ``observe_window``) is charged
-        once per window, not once per group: that is the amortization.
-        Router-ahead prefetch targets the NEXT window's predicted sets
-        and drains through the union of this window's transfer_plan
-        slices."""
-        self.key, k = jax.random.split(self.key)
-        args = (self.params, cache, jnp.asarray(last_tok[:, None]),
-                jnp.asarray(active), jnp.asarray(rem), k)
-        chunk = self.ecfg.decode_chunk if self.ecfg.mode == "continuous" else 1
-        self._fwd_passes += chunk
-        if self._watchdog is not None:
-            self._watchdog.step_start()
-        if self.residency:
-            snap = self._resident_snap()
-            for r in self.residency.values():
-                r.pin_resident()
-            cache, tok, act2, _, toks, emitted, counts = \
-                self._decode_window_fn(*args, self._expert_state())
-            prefetching = bool(self.ecfg.prefetch and self.groups)
-            if prefetching:
-                self._enqueue_prediction(gids)
-                if self._predictors:
-                    self._enqueue_gate_predictions(holders)
-                self._drain_prefetch(gids, retry_refused=True)
-            res = (cache, np.array(tok)[:, 0], np.asarray(act2),
-                   np.asarray(toks), np.asarray(emitted))   # sync
-            self._watchdog_end()
-            hidden = {k: ((r.slot_of >= 0) & ~snap[k])
-                      for k, r in self.residency.items()}
-            for r in self.residency.values():
-                r.unpin_all()
-            if prefetching:
-                self._drain_prefetch(gids, retry_refused=False)
-            self._account_counts(counts, holders=holders, snap=snap,
-                                 hidden=hidden)
-            return res
-        cache, tok, act2, _, toks, emitted = self._decode_window_fn(*args)
-        res = (cache, np.array(tok)[:, 0], np.asarray(act2),
-               np.asarray(toks), np.asarray(emitted))   # sync
-        self._watchdog_end()
-        return res
 
     @staticmethod
     def _emit(toks, emitted, row_req):
@@ -1461,51 +1500,62 @@ class Engine:
     def _run_prefill(self, step_fn, *args):
         """Shared prefill wrapper (monolithic fill AND staged chunk)
         absorbing the expert-paged protocol: one fwd pass booked, the
-        residency snapshot taken at dispatch, activation counts
-        accounted.  Returns (logits, cache)."""
+        residency snapshot taken at dispatch, activation counts and
+        program-counted reads booked.  Returns (logits, cache)."""
         self._fwd_passes += 1
-        if self.residency:
-            snap = self._resident_snap()
-            logits, cache, counts = step_fn(self.params, *args,
-                                            self._expert_state())
-            self._account_counts(counts, snap=snap)
-            return logits, cache
-        return step_fn(self.params, *args)
+        if not self.residency:
+            return step_fn(self.params, *args)
+        snap = self._resident_snap()
+        with span("engine.dispatch"):
+            logits, cache, counts, reads = step_fn(self.params, *args,
+                                                   self._expert_state())
+        with span("engine.wait"):
+            counts, reads = jax.device_get((counts, reads))
+        with span("weights.book", self._wt):
+            self._account_counts(counts, reads, snap)
+        return logits, cache
 
     # ------------------------------------------------- continuous mode
     def _admit_continuous(self):
         """Fill freed slots: per admitted request, prefill at its own
         bucket width (batch 1) and slot-write the KV into the pool row.
         Re-admitted (preempted) requests prefill prompt + transcript."""
-        for slot in self.scheduler.admit_to_slots():
+        with span("sched.admit"):
+            admitted = self.scheduler.admit_to_slots()
+        for slot in admitted:
             r = slot.req
             eff = r.effective_prompt
             S = self._bucket(len(eff))
-            toks = np.zeros((1, S), np.int32)
-            toks[0, :len(eff)] = eff
-            logits, single = self._run_prefill(
-                self._prefill, jnp.asarray(toks), self._prefill_scratch,
-                jnp.asarray([len(eff)], np.int32))
-            first = self._sample_first(logits)
-            r.generated.append(first)
-            group = self.groups[slot.gid]
-            if self._kv is not None:
-                # book the prompt's blocks (alloc/fetch/spill-to-make-room)
-                # before the slot-insert scatters through the page table
-                idx = self._slot_of(slot)
-                _, ok, _ = self._kv_ensure(lambda: self._kv.ensure_tokens(
-                    idx, len(eff), self.ecfg.block_tokens, (idx,)))
-                assert ok, "admission exceeds the KV arena floor"
-                pooled = self._insert(self._compose_kv(group.cache, slot.gid),
-                                      single, slot.row)
-                group.cache = self._absorb_kv(pooled)
-            else:
-                group.cache = self._insert(group.cache, single, slot.row)
-            group.last_tok[slot.row] = first
-            if self._prefill_retires(r):
-                self._retire_slot(slot)
-            else:
-                self.scheduler.start_decode(slot)
+            with span("engine.prefill", rid=r.rid, width=S):
+                toks = np.zeros((1, S), np.int32)
+                toks[0, :len(eff)] = eff
+                logits, single = self._run_prefill(
+                    self._prefill, jnp.asarray(toks), self._prefill_scratch,
+                    jnp.asarray([len(eff)], np.int32))
+                first = self._sample_first(logits)
+                r.generated.append(first)
+                group = self.groups[slot.gid]
+                if self._kv is not None:
+                    # book the prompt's blocks (alloc/fetch/spill to make
+                    # room) before the slot-insert scatters through the
+                    # page table
+                    idx = self._slot_of(slot)
+                    _, ok, _ = self._kv_ensure(
+                        lambda: self._kv.ensure_tokens(
+                            idx, len(eff), self.ecfg.block_tokens, (idx,)))
+                    assert ok, "admission exceeds the KV arena floor"
+                    pooled = self._insert(
+                        self._compose_kv(group.cache, slot.gid), single,
+                        slot.row)
+                    group.cache = self._absorb_kv(pooled)
+                else:
+                    group.cache = self._insert(group.cache, single,
+                                               slot.row)
+                group.last_tok[slot.row] = first
+                if self._prefill_retires(r):
+                    self._retire_slot(slot)
+                else:
+                    self.scheduler.start_decode(slot)
 
     # -------------------------------------- overlapped (staged) admission
     def _prefill_tick(self) -> bool:
@@ -1529,9 +1579,10 @@ class Engine:
         n = min(rem, width)
         toks = np.zeros((1, width), np.int32)
         toks[0, :n] = eff[t:t + n]
-        logits, self._stage_scratch = self._run_prefill(
-            self._prefill_chunk, jnp.asarray(toks), self._stage_scratch,
-            jnp.asarray([n], np.int32))
+        with span("engine.prefill", rid=r.rid, width=width):
+            logits, self._stage_scratch = self._run_prefill(
+                self._prefill_chunk, jnp.asarray(toks), self._stage_scratch,
+                jnp.asarray([n], np.int32))
         # partial slot insert at the row offset: the chunk lands in the
         # pool immediately, so the final flip to DECODE copies nothing
         if self._kv is not None:
@@ -1583,7 +1634,8 @@ class Engine:
 
     def _step_continuous(self) -> bool:
         if self.ecfg.overlap:
-            self._staged.extend(self.scheduler.admit_to_slots())
+            with span("sched.admit"):
+                self._staged.extend(self.scheduler.admit_to_slots())
             did = self._prefill_tick()
             # cold pool: nothing is decodable yet, so drain prefill chunks
             # back-to-back instead of trickling one per (idle) tick
@@ -1615,9 +1667,10 @@ class Engine:
         # the youngest rows if this chunk could blow the group budget
         self.scheduler.enforce_budget(gid, self.ecfg.decode_chunk)
         if self._kv is not None:
-            self._kv_sweep()              # blocks of budget-preempted slots
-            # fetch/alloc this group's working set (may preempt more)
-            self._kv_prepare_group(gid, self.ecfg.decode_chunk)
+            with span("kv.prepare", self._kvt):
+                self._kv_sweep()          # blocks of budget-preempted slots
+                # fetch/alloc this group's working set (may preempt more)
+                self._kv_prepare_group(gid, self.ecfg.decode_chunk)
         slots = self.scheduler.slots[gid]
         active = np.array([s.state == SlotState.DECODE for s in slots])
         if not active.any():
@@ -1631,8 +1684,8 @@ class Engine:
         else:
             cache = group.cache
         cache, group.last_tok, act2, toks, emitted = \
-            self._decode_group(cache, group.last_tok, active, rem,
-                               holder=group, gid=gid)
+            self._decode(cache, group.last_tok, active, rem,
+                         holders=[group], gid=gid)
         group.cache = (self._absorb_kv(cache)
                        if self._kv is not None else cache)
         self.tokens_out += self._emit(
@@ -1645,8 +1698,9 @@ class Engine:
             # the KV analogue of the router-ahead weight prefetch:
             # while this group's results land, stream the next
             # group's spilled blocks back in transfer_plan slices
-            self._kv_enqueue_prefetch(gid)
-            self._kv_drain_prefetch(gid)
+            with span("kv.prefetch", self._kvt):
+                self._kv_enqueue_prefetch(gid)
+                self._kv_drain_prefetch(gid)
 
     def _tick_window_continuous(self, gids: List[int]) -> None:
         """One module-batched accumulation window: the attention phase
@@ -1662,10 +1716,12 @@ class Engine:
         for gid in gids:
             self.scheduler.enforce_budget(gid, self.ecfg.decode_chunk)
         if self._kv is not None:
-            self._kv_sweep()
-            # the window dispatches combined: the whole window's working
-            # set must be device-resident at once (union protect set)
-            self._kv_prepare_group(gids, self.ecfg.decode_chunk)
+            with span("kv.prepare", self._kvt):
+                self._kv_sweep()
+                # the window dispatches combined: the whole window's
+                # working set must be device-resident at once (union
+                # protect set)
+                self._kv_prepare_group(gids, self.ecfg.decode_chunk)
         slot_rows = [self.scheduler.slots[g] for g in gids]
         active = np.array([s.state == SlotState.DECODE
                            for slots in slot_rows for s in slots])
@@ -1682,9 +1738,9 @@ class Engine:
             cache = self._compose_kv(dense, gids)
         else:
             cache = dense
-        cache, last2, act2, toks, emitted = self._decode_window(
+        cache, last2, act2, toks, emitted = self._decode(
             cache, last, active, rem,
-            holders=[self.groups[g] for g in gids], gids=gids)
+            holders=[self.groups[g] for g in gids], gid=gids)
         dense_out = self._absorb_kv(cache) if self._kv is not None else cache
         for j, (g, part) in enumerate(zip(
                 gids, kvcache.split_slot_cache(dense_out, len(gids)))):
@@ -1700,8 +1756,9 @@ class Engine:
                 if s.state == SlotState.DECODE and not act2[j * b + i]:
                     self._retire_slot(s)
         if self._kv is not None and self.ecfg.kv_prefetch:
-            self._kv_enqueue_prefetch(gids)
-            self._kv_drain_prefetch(gids)
+            with span("kv.prefetch", self._kvt):
+                self._kv_enqueue_prefetch(gids)
+                self._kv_drain_prefetch(gids)
 
     # ----------------------------------------------------- static mode
     def _admit_static(self):
@@ -1711,7 +1768,9 @@ class Engine:
         # shared arena — the policy budget is enforced by allocation, not
         # by the group cap alone)
         avail = self.ecfg.num_ubs - len(self.active)
-        for group in self.scheduler.admit(avail):
+        with span("sched.admit"):
+            admitted = self.scheduler.admit(avail)
+        for group in admitted:
             mu = self.ecfg.ubatch
             S = self._bucket(max(r.input_len for r in group))
             toks = np.zeros((mu, S), np.int32)
@@ -1721,9 +1780,10 @@ class Engine:
                 lens[i] = r.input_len
             # rows beyond len(group) are padding rows (len 0 → masked)
             cache = kvcache.init_cache(self.cfg, mu, self.ecfg.max_seq)
-            logits, cache = self._run_prefill(self._prefill,
-                                              jnp.asarray(toks), cache,
-                                              jnp.asarray(lens))
+            with span("engine.prefill", rid=group[0].rid, width=S):
+                logits, cache = self._run_prefill(self._prefill,
+                                                  jnp.asarray(toks), cache,
+                                                  jnp.asarray(lens))
             self.key, k = jax.random.split(self.key)
             first = np.asarray(
                 sample(logits, k, temperature=self.ecfg.temperature))
@@ -1810,8 +1870,8 @@ class Engine:
         else:
             cache = ab.cache
         cache, ab.last_tokens, act2, toks, emitted = \
-            self._decode_group(cache, np.asarray(ab.last_tokens),
-                               active, rem, holder=ab)
+            self._decode(cache, np.asarray(ab.last_tokens),
+                         active, rem, holders=[ab], gid=None)
         ab.cache = (self._absorb_kv(cache)
                     if self._kv is not None else cache)
         row_req = [ab.requests[i] if i < len(ab.requests) else None
@@ -1843,9 +1903,9 @@ class Engine:
         active = np.concatenate([a for _, a, _ in window])
         rem = np.concatenate([r for _, _, r in window])
         last = np.concatenate([np.asarray(ab.last_tokens) for ab in abs_])
-        cache, last2, act2, toks, emitted = self._decode_window(
+        cache, last2, act2, toks, emitted = self._decode(
             cache, last, active, rem, holders=abs_,
-            gids=[ab.gid for ab in abs_])
+            gid=[ab.gid for ab in abs_])
         dense_out = self._absorb_kv(cache) if self._kv is not None else cache
         for j, (ab, part) in enumerate(zip(
                 abs_, kvcache.split_slot_cache(dense_out, len(abs_)))):

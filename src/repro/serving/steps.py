@@ -7,8 +7,10 @@ These are the functions the dry-run lowers for the ``prefill_*`` /
 Expert-granular paging (core.paging.PagedWeights with expert manifests)
 changes the step signatures: each step takes a trailing ``expert_state``
 pytree ({key: (pool, resident_map)} — the device residency snapshot) and
-returns per-layer expert activation counts so the engine's host-side
-residency cache can learn popularity and account H2D traffic.
+returns per-layer expert activation counts, so the engine's host-side
+residency cache can learn popularity and book H2D traffic, and per-layer
+expert-span reads ({key: (..., n_steps, 2)}: [host-store reads, pool
+reads]) counted where the program executes them.
 ``_expert_granular`` is the single switch deciding which shape a factory
 produces.  With paged weights of either granularity the factory holds
 only the manifests: the page stores arrive in ``params["blocks"]`` on
@@ -89,7 +91,7 @@ def make_prefill_fill_step(cfg: ModelConfig,
             out["hidden"], idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         logits = unembed(cfg, params, hidden)
         if expert:
-            return logits, cache, out["expert_counts"]
+            return logits, cache, out["expert_counts"], out["expert_reads"]
         return logits, cache
 
     return prefill_step
@@ -124,7 +126,8 @@ def make_prefill_chunk(cfg: ModelConfig, policy: Optional[ExecPolicy] = None,
             out["hidden"], idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         logits = unembed(cfg, params, hidden)
         if expert:
-            return logits, out["cache"], out["expert_counts"]
+            return (logits, out["cache"], out["expert_counts"],
+                    out["expert_reads"])
         return logits, out["cache"]
 
     return prefill_chunk
@@ -170,10 +173,12 @@ def make_decode_chunk(cfg: ModelConfig, policy: Optional[ExecPolicy] = None,
     refill — it must never be read without that reset.
 
     Expert-granular paging adds a trailing ``expert_state`` arg (the
-    residency snapshot, constant across the chunk) and a trailing
-    ``counts`` output ({key: (chunk, n_steps, E)} — per inner step, so
-    the host accounting books each step's distinct activations against
-    the snapshot it actually read).
+    residency snapshot, constant across the chunk) and trailing
+    ``counts`` ({key: (chunk, n_steps, E)} — per inner step, so the host
+    accounting books each step's distinct activations against the
+    snapshot it actually read) and ``reads`` ({key: (chunk, n_steps, 2)}
+    — the spans each step read from the host store and from the pool)
+    outputs.
 
     token_groups=G (module-based batching): B is G·ubatch — the engine
     concatenates G rotation groups' slot caches and the MoE FFN stages
@@ -203,14 +208,15 @@ def make_decode_chunk(cfg: ModelConfig, policy: Optional[ExecPolicy] = None,
             rem2 = rem - emitted.astype(jnp.int32)
             active2 = active & (nxt != eos_id) & (rem2 > 0)
             tok2 = jnp.where(active, nxt, tok[:, 0])[:, None]
-            ys = (nxt, emitted) + ((out["expert_counts"],) if expert else ())
+            ys = (nxt, emitted) + ((out["expert_counts"],
+                                    out["expert_reads"]) if expert else ())
             return (new_cache, tok2, active2, rem2, key), ys
 
         (cache, tok, active, rem, key), ys = jax.lax.scan(
             body, (cache, tok, active, rem, key), None, length=chunk)
         if expert:
-            toks, emitted, counts = ys
-            return cache, tok, active, rem, toks, emitted, counts
+            toks, emitted, counts, reads = ys
+            return cache, tok, active, rem, toks, emitted, counts, reads
         toks, emitted = ys
         return cache, tok, active, rem, toks, emitted
 

@@ -314,3 +314,187 @@ def test_prefetch_drains_through_transfer_plan(mixtral_setup, monkeypatch):
            prefetch=True)
     assert calls, "prefetch never consulted transfer_plan"
     assert all(n == 2 for _, n in calls)          # num_ubs slices
+
+
+# ---------------------------------------------------------------------------
+# Expert-span reads counted in the programs; engine spans and program names
+# ---------------------------------------------------------------------------
+
+def _recording_engine(cfg, params, **kw):
+    """An expert-paged engine whose decode and prefill programs record,
+    per call, the token count, the dispatch snapshot's resident map and
+    the returned activation counts and reads."""
+    from repro.serving.engine import Engine, EngineConfig
+    eng = Engine(cfg, params, EngineConfig(ubatch=2, num_ubs=2, max_seq=64,
+                                           page_elems=4096, expert_paged=True,
+                                           **kw))
+    calls = []
+
+    def record(fn, window, prefill=False):
+        def call(*args):
+            (_, rmap), = args[-1].values()
+            resident = np.asarray(rmap) >= 0
+            # prefill: every position of (1, S) tokens; decode: B rows
+            tokens = int(np.prod(args[1].shape)) if prefill \
+                else int(args[2].shape[0])
+            out = fn(*args)
+            counts, reads = out[-2:]
+            (c,), (r,) = counts.values(), reads.values()
+            calls.append((tokens, resident, np.asarray(c), np.asarray(r),
+                          window))
+            return out
+        return call
+
+    eng._prefill = record(eng._prefill, False, prefill=True)
+    eng._decode_chunk = record(eng._decode_chunk, False)
+    if eng._decode_window_fn is not None:
+        eng._decode_window_fn = record(eng._decode_window_fn, True)
+    return eng, calls
+
+
+def _expected_host_reads(cfg, tokens, resident, counts, window):
+    """Host reads per (pass, layer) from the routing alone: every
+    activated expert whose span the snapshot lacks, plus one read of
+    expert 0 per padding entry of the activated set when expert 0 is
+    not resident (``moe.activated_experts`` pads ``sel`` with 0)."""
+    act = counts > 0
+    if window:
+        act = act.any(axis=-2)
+    act = act.reshape(-1, *resident.shape)             # (passes, L, E)
+    A = min(cfg.num_experts, tokens * cfg.top_k)
+    asked = (act & ~resident).sum(-1)
+    pad = (A - act.sum(-1)) * ~resident[:, 0]
+    return asked, pad
+
+
+@pytest.mark.parametrize("module_batch", [False, True],
+                         ids=["lockstep", "window"])
+def test_program_counts_every_host_read(mixtral_setup, module_batch):
+    """With a chunk of 8 and a mostly empty pool, the reads the programs
+    count equal, per pass and layer, an independent count from the
+    dispatch snapshot and the routing (padding entries included); the
+    engine's totals are their sums, and the bytes the programs read
+    exceed what the host books."""
+    cfg, params = mixtral_setup
+    eng, calls = _recording_engine(cfg, params, expert_slots=2,
+                                   decode_chunk=8, module_batch=module_batch)
+    rng = np.random.default_rng(11)
+    for _ in range(5):
+        eng.submit(rng.integers(2, cfg.vocab_size, int(rng.integers(4, 20))),
+                   12)
+    eng.run_until_idle()
+    host = pool = pads = 0
+    for tokens, resident, counts, reads, window in calls:
+        asked, pad = _expected_host_reads(cfg, tokens, resident, counts,
+                                          window)
+        got = reads.reshape(-1, *reads.shape[-2:])       # (passes, L, 2)
+        A = min(cfg.num_experts, tokens * cfg.top_k)
+        np.testing.assert_array_equal(got[..., 0], asked + pad)
+        np.testing.assert_array_equal(got[..., 0] + got[..., 1], A)
+        host += int(got[..., 0].sum())
+        pool += int(got[..., 1].sum())
+        pads += int(pad.sum())
+    assert any(w for *_, w in calls) == module_batch
+    t = eng.weight_traffic()
+    span = eng.residency["p0"].span_bytes
+    assert (t["read_spans"], t["pool_reads"], t["pad_reads"]) \
+        == (host, pool, pads)
+    assert pads > 0 and pool > 0
+    assert t["read_bytes"] == host * span
+    assert t["read_bytes"] > t["expert_bytes"] > 0
+
+
+def _span_events(trace_dir):
+    import glob
+    from jax.profiler import ProfileData
+    path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[-1]
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:")
+            for line in p.lines for e in line.events
+            if e.name.startswith("repro.")]
+
+
+# each span with the spans it must sit inside (any of them)
+_NESTING = {
+    "repro.engine.step": (),
+    "repro.sched.admit": ("repro.engine.step",),
+    "repro.engine.prefill": ("repro.engine.step",),
+    "repro.engine.decode": ("repro.engine.step",),
+    "repro.engine.dispatch": ("repro.engine.decode", "repro.engine.prefill"),
+    "repro.engine.wait": ("repro.engine.decode", "repro.engine.prefill"),
+    "repro.weights.book": ("repro.engine.decode", "repro.engine.prefill"),
+    "repro.weights.prefetch": ("repro.engine.decode",),
+    "repro.weights.copy": ("repro.weights.book", "repro.weights.prefetch"),
+    "repro.kv.prepare": ("repro.engine.step",),
+    "repro.kv.prefetch": ("repro.engine.step",),
+    "repro.kv.spill": ("repro.kv.prepare", "repro.kv.prefetch"),
+    "repro.kv.fetch": ("repro.kv.prepare", "repro.kv.prefetch"),
+}
+
+
+def test_step_trace_holds_nested_spans(mixtral_setup, tmp_path):
+    """A profiler trace of one Engine.step (expert-paged weights, paged
+    KV that spills) holds every engine span, each inside its parent;
+    the layers' host seconds and the KV programs are counted."""
+    import jax
+    from repro.serving.engine import Engine, EngineConfig
+    cfg, params = mixtral_setup
+    eng = Engine(cfg, params, EngineConfig(
+        ubatch=2, num_ubs=2, max_seq=64, page_elems=4096, expert_paged=True,
+        w_gpu_ratio=0.125, kv_paged=True, kv_gpu_ratio=0.25))
+    rng = np.random.default_rng(0)
+    for _ in range(6):
+        eng.submit(rng.integers(2, cfg.vocab_size, int(rng.integers(8, 30))),
+                   20)
+    eng.step()
+    eng.step()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    ev = _span_events(tmp_path)
+    assert {n for n, *_ in ev} == set(_NESTING)
+    assert sum(n == "repro.engine.step" for n, *_ in ev) == 1
+    for name, s, e in ev:
+        parents = _NESTING[name]
+        assert not parents or any(
+            p == pn and ps <= s and e <= pe
+            for p in parents for pn, ps, pe in ev), name
+    assert eng.weight_traffic()["host_s"] > 0
+    kv = eng.kv_traffic()
+    assert kv["host_s"] > 0 and kv["dispatches"] > 0
+
+
+@pytest.mark.parametrize("overlap", [False, True],
+                         ids=["monolithic", "staged"])
+def test_program_names_match_the_trace_readers(mixtral_setup, overlap):
+    """Every program the engine compiles has a name: the decode programs
+    and only they contain ``decode_chunk``, the prefill programs and
+    only they contain ``prefill``, and none is a lambda."""
+    import jax
+    cfg, params = mixtral_setup
+    names = []
+
+    def on_compile(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            names.append(str(kw.get("fun_name")))
+
+    jax.clear_caches()          # compile every program this engine uses
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        eng, _ = _serve(cfg, params, [(np.arange(2, 30), 12)] * 4,
+                        expert_paged=True, w_gpu_ratio=0.125, kv_paged=True,
+                        kv_gpu_ratio=0.25, overlap=overlap,
+                        module_batch=True)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert eng.kv_traffic()["spills"] > 0
+    progs = {n[len("jit("):-1] for n in names}         # "jit(<name>)"
+    assert {n for n in progs if "decode_chunk" in n} == {"decode_chunk"}
+    assert {n for n in progs if "prefill" in n} == {
+        "prefill_chunk" if overlap else "prefill_step"}
+    assert not [n for n in progs if "lambda" in n]
+    assert {"kv_spill_read", "kv_fetch_write", "kv_clear",
+            "pool_write"} <= progs

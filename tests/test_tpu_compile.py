@@ -14,6 +14,7 @@ library), and the persistent compilation cache is off around these
 compiles (an entry written for a described chip cannot be read back
 without one)."""
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,8 +63,12 @@ def _compile(fn, *args, in_shardings=None):
     return jax.jit(fn, **kw).lower(*args).compile()
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name):
+    """The kernel is in the program, under its stable name: the custom
+    call's instruction is what the device trace shows."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call", text), name
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
@@ -90,7 +95,7 @@ def test_paged_gqa_decode_compiles(one_chip, fused, int8):
                                        interpret=False,
                                        **dict(zip(names, extra)))
 
-    _assert_kernel(_compile(fn, *args))
+    _assert_kernel(_compile(fn, *args), "paged_gqa_decode")
 
 
 @pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
@@ -110,7 +115,7 @@ def test_paged_mla_decode_compiles(one_chip, fused):
                                        scale=(lat + dr) ** -0.5, lat=lat,
                                        interpret=False, **kw)
 
-    _assert_kernel(_compile(fn, *args))
+    _assert_kernel(_compile(fn, *args), "paged_mla_decode")
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
@@ -128,7 +133,7 @@ def test_gqa_decode_compiles(one_chip, int8):
         return _gqa.gqa_decode(q, k, v, valid, scale=D ** -0.5,
                                interpret=False, **kw)
 
-    _assert_kernel(_compile(fn, *args))
+    _assert_kernel(_compile(fn, *args), "gqa_decode")
 
 
 def test_flash_prefill_compiles(one_chip):
@@ -140,7 +145,7 @@ def test_flash_prefill_compiles(one_chip):
     def fn(q, k, v, kv_len):
         return _flash.flash_prefill(q, k, v, kv_len=kv_len, interpret=False)
 
-    _assert_kernel(_compile(fn, *args))
+    _assert_kernel(_compile(fn, *args), "flash_prefill")
 
 
 def test_expert_fetch_from_pinned_host_store(one_chip):
