@@ -21,11 +21,12 @@ Two manifest granularities:
   * split (``pack_layer_stack_split`` / ``pack_block_groups_split``): each
     layer's manifest is divided into a *shared* span (attention / norm /
     router / shared-expert leaves, streamed every layer as before) and
-    per-(layer, expert) spans for the routed expert weights, with a
-    ``(layer, expert) → page ids`` table.  Top-k routing touches only a
-    fraction of the experts, so the serving engine can gather just the
-    activated experts' spans (core.residency keeps the popular ones
-    device-resident) instead of the full E-expert block.
+    per-(layer, expert) spans for the routed expert weights.  Top-k
+    routing touches only a fraction of the experts, so the serving
+    engine reads just the activated experts' spans (core.residency keeps
+    the popular ones device-resident) instead of the full E-expert block.
+    An expert span is not paged: it is one row of (d_model, d_ff) blocks
+    (``ExpertManifest``) that the expert's FFN reads where it lies.
 """
 from __future__ import annotations
 
@@ -41,14 +42,18 @@ import numpy as np
 
 @dataclass(frozen=True)
 class LeafEntry:
+    """Where one leaf lies in a span.  In a layer span (``PageManifest``)
+    ``offset`` counts elements of the flat span; in an expert span
+    (``ExpertManifest``) it counts (d_model, d_ff) blocks."""
     path: Tuple[str, ...]
     shape: Tuple[int, ...]       # per-layer shape (stack dim removed)
     dtype: str
-    offset: int                  # element offset within the layer's flat span
+    offset: int                  # where the leaf starts (see above)
     perm: Tuple[int, ...] = ()   # axis order the span stores it in (() = as is)
 
     def restore(self, flat, lead: Tuple[int, ...] = ()):
-        """(*lead, n) flat slice of a span -> (*lead, *shape)."""
+        """(*lead, n) slice of a span holding this leaf in its storage
+        order (flat, or as blocks) -> (*lead, *shape)."""
         if not self.perm:
             return flat.reshape(lead + self.shape)
         x = flat.reshape(lead + tuple(self.shape[i] for i in self.perm))
@@ -182,11 +187,18 @@ def fetch_pages(pages: jax.Array, page_ids) -> jax.Array:
 # Routed-expert leaves inside a "moe" subtree (shared experts stay in the
 # shared span — they run for every token, so streaming them per layer is
 # already optimal).  The int8 dequant scales (wi_scale/wo_scale) also stay
-# in the shared span: they are 4 bytes per expert — page-padding them into
-# expert spans would waste a page each, and the expert pool is packed at
-# the expert-weight dtype, which would truncate float32 scales.  moe_paged
-# gathers them per activated expert from the shared params instead.
+# in the shared span: they are 4 bytes per expert, and the expert store
+# is packed at the expert-weight dtype, which would truncate float32
+# scales.  moe_paged reads them per activated expert from the shared
+# params instead.
 EXPERT_LEAF_NAMES = ("wi", "wo")
+
+# Axis order each routed-expert leaf is stored in: d_ff minor, d_model
+# second-minor.  The gate/up leaf (D, 2, F) becomes two (D, F) blocks,
+# the down projection (F, D) one transposed (D, F) block, so every block
+# of a span shares one tiled layout and each is a slice of the span's
+# most major axis: the FFN's dots read the blocks in place.
+EXPERT_STORAGE_PERM = {"wi": (1, 0, 2), "wo": (1, 0)}
 
 
 def _is_expert_leaf(path: Tuple[str, ...]) -> bool:
@@ -206,28 +218,43 @@ def _tree_from_leaves(leaves):
 
 @dataclass
 class ExpertManifest:
-    """Per-(layer, expert) page spans for one stacked layer group.  The
-    span unit is ONE expert's weights in ONE layer — the granularity the
-    residency cache pins/evicts and the router-gated gather fetches."""
-    page_elems: int
-    expert_elems: int            # padded flat elements per (layer, expert)
-    pages_per_expert: int
+    """Per-(layer, expert) spans for one stacked layer group.  The span
+    unit is ONE expert's weights in ONE layer — the granularity the
+    residency cache pins/evicts and the router-gated fetch reads — stored
+    as ``span_shape`` = (blocks, d_model, d_ff): the gate and up halves
+    of ``wi``, then ``wo`` transposed (``EXPERT_STORAGE_PERM``).  A span
+    is one row of the host store (one host→device copy carries the whole
+    expert) and one slot of the device pool; ``leaves`` give each leaf's
+    first block."""
     num_layers: int
     num_experts: int
-    leaves: List[LeafEntry]      # paths relative to the moe subtree
+    block_shape: Tuple[int, int]   # (d_model, d_ff)
+    blocks: int                    # blocks per span
+    leaves: List[LeafEntry]        # paths relative to the moe subtree
     dtype: str
 
-    def expert_pages(self, layer: int, expert: int) -> np.ndarray:
-        """The (layer, expert) → page ids table (flat pool numbering)."""
-        start = ((layer * self.num_experts + expert)
-                 * self.pages_per_expert)
-        return np.arange(start, start + self.pages_per_expert)
+    @property
+    def span_shape(self) -> Tuple[int, int, int]:
+        return (self.blocks,) + tuple(self.block_shape)
 
     @property
     def span_bytes(self) -> int:
-        """H2D bytes one expert span moves (padded, what a transfer costs)."""
-        return (self.pages_per_expert * self.page_elems
-                * np.dtype(self.dtype).itemsize)
+        """Bytes one expert span moves (what a host→device copy costs)."""
+        return int(np.prod(self.span_shape)) * np.dtype(self.dtype).itemsize
+
+    def leaf_size(self, e: LeafEntry) -> int:
+        """Blocks leaf ``e`` takes in a span."""
+        return int(np.prod(e.shape)) // int(np.prod(self.block_shape))
+
+    def leaf_blocks(self, block) -> Dict[str, Tuple]:
+        """One span's leaves in their storage layout, by leaf name, each
+        the tuple of its blocks: {"wi": (gate, up), "wo": (wo
+        transposed,)}.  ``block(i)`` reads the span's i-th block where it
+        lies, so the caller decides where the blocks come from and no
+        leaf is cut out of the span as a whole."""
+        return {e.path[-1]: tuple(block(e.offset + j)
+                                  for j in range(self.leaf_size(e)))
+                for e in self.leaves}
 
 
 @dataclass
@@ -236,51 +263,69 @@ class SplitManifest:
     experts: Optional[ExpertManifest]
 
 
-def expert_manifest(expert_leaves, page_elems: int = 1 << 20
-                    ) -> ExpertManifest:
+def expert_manifest(expert_leaves) -> ExpertManifest:
     """The manifest ``pack_expert_stack`` packs by, from the leaves'
     shapes and dtypes alone (arrays or ``jax.ShapeDtypeStruct``s).  Leaf
-    paths are stored relative to the ``moe`` subtree so a gathered span
-    unflattens straight into the MoE param dict."""
+    paths are stored relative to the ``moe`` subtree so a span unflattens
+    straight into the MoE param dict."""
     L, NE = expert_leaves[0][1].shape[:2]
     dtype = expert_leaves[0][1].dtype
     entries: List[LeafEntry] = []
-    offset = 0
+    block_shape, offset = None, 0
     for path, leaf in expert_leaves:
         assert leaf.shape[:2] == (L, NE), f"expert stack mismatch at {path}"
         rel = path[path.index("moe") + 1:]
-        per = int(np.prod(leaf.shape[2:])) if len(leaf.shape) > 2 else 1
-        entries.append(LeafEntry(rel, tuple(leaf.shape[2:]), str(leaf.dtype),
-                                 offset, _storage_perm(leaf.shape[2:])))
-        offset += per
-    pages_per_expert = _span_pages(offset, page_elems, dtype)
-    return ExpertManifest(page_elems, pages_per_expert * page_elems,
-                          pages_per_expert, L, NE, entries, str(dtype))
+        shape = tuple(leaf.shape[2:])
+        perm = EXPERT_STORAGE_PERM[path[-1]]
+        stored = tuple(shape[i] for i in perm)
+        block_shape = block_shape or stored[-2:]
+        assert stored[-2:] == block_shape, f"{path} stores as {stored}"
+        entries.append(LeafEntry(rel, shape, str(leaf.dtype), offset, perm))
+        offset += int(np.prod(stored[:-2], dtype=np.int64))
+    return ExpertManifest(L, NE, block_shape, offset, entries, str(dtype))
 
 
-def pack_expert_stack(expert_leaves, page_elems: int = 1 << 20
-                      ) -> Tuple[jax.Array, ExpertManifest]:
+def _copy_leaf(dst: np.ndarray, src: np.ndarray, tile: int = 256):
+    """dst[...] = src.  A source whose minor axis is strided (a leaf read
+    transposed) is copied a tile of the two minor axes at a time: copied
+    whole, a Mixtral-width (F, D) leaf runs several times slower than
+    tiles that stay in cache."""
+    if src.strides[-1] == src.itemsize:
+        dst[...] = src
+        return
+    rows, cols = dst.shape[-2:]
+    for i in range(0, rows, tile):
+        for j in range(0, cols, tile):
+            dst[..., i:i + tile, j:j + tile] = src[..., i:i + tile, j:j + tile]
+
+
+def pack_expert_stack(expert_leaves) -> Tuple[np.ndarray, ExpertManifest]:
     """expert_leaves: [(path, arr (L, E, ...))].  Returns
-    (pages (L, E, pages_per_expert, page_elems), manifest)."""
-    manifest = expert_manifest(expert_leaves, page_elems)
-    pages = _pack_pages([leaf for _, leaf in expert_leaves],
-                        perms=tuple(e.perm for e in manifest.leaves), lead=2,
-                        elems=manifest.expert_elems, page_elems=page_elems,
-                        dtype=np.dtype(manifest.dtype))
-    return pages, manifest
+    (store (L, E, *span_shape), manifest), built in host memory with
+    numpy like ``_pack_pages``."""
+    em = expert_manifest(expert_leaves)
+    out = np.empty((em.num_layers, em.num_experts) + em.span_shape,
+                   np.dtype(em.dtype))
+    for (_, leaf), e in zip(expert_leaves, em.leaves):
+        x = np.asarray(leaf).transpose((0, 1) + tuple(2 + i for i in e.perm))
+        k = int(np.prod(x.shape[2:-2], dtype=np.int64))
+        _copy_leaf(out[:, :, e.offset:e.offset + k],
+                    x.reshape(x.shape[:2] + (k,) + x.shape[-2:]))
+    return out, em
 
 
 def unflatten_expert_span(span: jax.Array, em: ExpertManifest) -> Dict:
-    """Rebuild expert params from page spans with arbitrary leading batch
-    dims: span (..., pages_per_expert, page_elems) -> pytree whose leaves
-    have shape (..., *leaf_shape) — the compacted (A, ...) expert subset
-    the two-phase MoE step computes on."""
-    lead = span.shape[:-2]
-    flat = span.reshape(lead + (-1,))
+    """Rebuild expert params from spans with arbitrary leading batch dims:
+    span (..., *span_shape) -> {leaf: (..., *leaf_shape)}, the leaves
+    transposed back from their storage order.  The serving path reads
+    spans in place and never calls this; only the stacked-subset compute
+    (``moe._grouped_subset``, the Pallas ``moe_ffn`` kernel), which wants
+    the (A, D, 2, F) / (A, F, D) model layout, does."""
+    lead = span.shape[:-3]
     out: Dict = {}
     for e in em.leaves:
-        n = int(np.prod(e.shape)) if e.shape else 1
-        leaf = e.restore(flat[..., e.offset:e.offset + n], lead)
+        k = em.leaf_size(e)
+        leaf = e.restore(span[..., e.offset:e.offset + k, :, :], lead)
         node = out
         for p in e.path[:-1]:
             node = node.setdefault(p, {})
@@ -296,7 +341,7 @@ def pack_layer_stack_split(stacked: Dict, page_elems: int = 1 << 20
     per-(layer, expert) spans for the routed expert weights.
 
     Returns (shared_pages (L*ppl, page_elems),
-             expert_pages (L, E, pages_per_expert, page_elems) or None,
+             expert store (L, E, *ExpertManifest.span_shape) or None,
              SplitManifest)."""
     leaves = _flatten_with_paths(stacked)
     expert_leaves = [(p, l) for p, l in leaves if _is_expert_leaf(p)]
@@ -305,7 +350,7 @@ def pack_layer_stack_split(stacked: Dict, page_elems: int = 1 << 20
         _tree_from_leaves(shared_leaves), page_elems)
     if not expert_leaves:
         return shared_pages, None, SplitManifest(shared_manifest, None)
-    expert_pages, em = pack_expert_stack(expert_leaves, page_elems)
+    expert_pages, em = pack_expert_stack(expert_leaves)
     return shared_pages, expert_pages, SplitManifest(shared_manifest, em)
 
 
@@ -313,12 +358,12 @@ def pack_layer_stack_split(stacked: Dict, page_elems: int = 1 << 20
 class PagedWeights:
     """Engine-facing bundle for split (expert-granular) paging: per-group
     shared spans shaped for the layer scan, plus the per-(layer, expert)
-    page pools and manifests for every MoE group.  Groups without routed
+    span stores and manifests for every MoE group.  Groups without routed
     experts appear only in ``pages``/``manifests`` (identical to the
     whole-layer path)."""
     pages: Dict[str, jax.Array]              # key -> (L, ppl, page_elems)
     manifests: Dict[str, PageManifest]
-    expert_pages: Dict[str, jax.Array]       # key -> (L, E, ppe, page_elems)
+    expert_pages: Dict[str, jax.Array]       # key -> (L, E, *span_shape)
     expert_manifests: Dict[str, ExpertManifest]
 
     def shared_layer_bytes(self, key: str) -> int:

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,59 +43,104 @@ class ExecPolicy:
     scan_unroll: int = 1
 
 
+class ExpertFetch(NamedTuple):
+    """One layer's router-gated expert fetch (``_ExpertCtx.make_fetch``),
+    the contract ``moe.moe_paged`` calls."""
+    each: Callable     # (sel, n_act, apply, acc) -> (acc, reads (2,))
+    stacked: Callable  # (sel, n_act) -> ({wi, wo} (A, ...), reads (2,))
+
+
 @dataclass
 class _ExpertCtx:
     """Scan-invariant state for one group's expert-granular paged weights:
-    the host page store, its manifest, and (optionally) the device
+    the host span store, its manifest, and (optionally) the device
     residency pool + (layer, expert) → slot map snapshot."""
-    pages: Any                            # (L, E, ppe, page_elems) host store
+    pages: Any                            # (L, E, *span_shape) host store
     manifest: Any                         # paging.ExpertManifest
-    pool: Optional[Any] = None            # (slots, ppe, page_elems) device
+    pool: Optional[Any] = None            # (slots, *span_shape) device
     resident_map: Optional[Any] = None    # (L, E) int32, -1 = host only
 
-    def make_fetch(self, layer):
-        """Bind the traced layer index: fetch(sel (A,)) gathers the
-        activated experts' spans — a resident span is read in place from
-        the pool, a miss comes from the host store — and rebuilds the
-        compacted (A, ...) expert params.  Each missed span is sliced
-        from the store on its own and moved to device memory explicitly
-        (on TPU the store lives in pinned host memory, so that move IS
-        the H2D transfer; a gather on a host operand does not compile),
-        so only the selected, non-resident spans cross the link, never
-        the layer's full (E, ppe, page_elems) slice.
+    def make_fetch(self, layer) -> ExpertFetch:
+        """Bind the traced layer index.  ``each(sel, n_act, apply, acc)
+        -> (acc, reads)``: for each entry ``a`` of the activated set
+        ``sel`` (A,), one ``lax.switch`` runs
+        ``acc = apply(a, leaves, acc)`` with ``leaves`` the expert's
+        leaves in their storage layout, by name
+        (``paging.ExpertManifest.leaf_blocks``), read where they lie:
 
-        Returns (params, reads): reads (2,) int32 counts the branches
-        this call executes — [spans read from the host store, spans read
-        from the pool] — over every entry of ``sel``, padding included."""
+          * a resident span straight from its pool slot;
+          * a miss sliced from the store on its own and moved to device
+            memory explicitly, one copy per expert (on TPU the store lives
+            in pinned host memory, so that move IS the host→device
+            transfer; a gather on a host operand does not compile);
+          * a padding entry (``a >= n_act``) reads nothing and leaves
+            ``acc`` as it is.
+
+        ``apply`` returns something ``acc``-shaped, so no span crosses a
+        branch boundary and nothing is stacked unless ``apply`` stacks it.
+        reads (2,) int32 counts the branches taken: [spans read from the
+        host store, spans read from the pool].
+
+        ``stacked(sel, n_act)`` is ``each`` with an ``apply`` that stacks
+        the spans (padding entries stay zero) and rebuilds the (A, ...)
+        subset in the model layout, for the compute that needs it."""
         from repro.core import offload as _offload
         from repro.core import paging as _paging
 
-        def fetch(sel):
-            L, E, ppe, pe = self.pages.shape
+        em = self.manifest
+
+        def each(sel, n_act, apply, acc):
+            L, E = self.pages.shape[:2]
             # (layer, expert) -> one major index: a host slice may cut
             # only the most major dimension
-            store = self.pages.reshape(L * E, ppe, pe)
+            store = self.pages.reshape((L * E,) + em.span_shape)
 
-            def from_host(e):
-                return _offload.device_operand(jax.lax.dynamic_index_in_dim(
-                    store, layer * E + e, 0, keepdims=False))
+            def from_host(a, acc):
+                with jax.named_scope("expert_fetch"):
+                    span = _offload.device_operand(
+                        jax.lax.dynamic_index_in_dim(
+                            store, layer * E + sel[a], 0, keepdims=False))
+                return apply(a, em.leaf_blocks(lambda i: span[i]), acc)
 
-            A = sel.shape[0]
+            live = jnp.arange(sel.shape[0]) < n_act
             if self.pool is None:
-                spans = [from_host(sel[a]) for a in range(A)]
-                n_host = jnp.int32(A)
+                resident = jnp.zeros_like(live)
+                readers = (from_host,)
             else:
+                # one block per index: each dot reads its slot's block in
+                # place (a dynamic slice of the whole slot is materialized)
+                blocks = self.pool.reshape((-1,) + tuple(em.block_shape))
                 slots = self.resident_map[layer, sel]
-                spans = [jax.lax.cond(
-                    slots[a] >= 0,
-                    lambda a=a: self.pool[jnp.maximum(slots[a], 0)],
-                    lambda a=a: from_host(sel[a])) for a in range(A)]
-                n_host = jnp.sum(slots < 0, dtype=jnp.int32)
-            reads = jnp.stack([n_host, A - n_host])
-            return (_paging.unflatten_expert_span(jnp.stack(spans),
-                                                  self.manifest), reads)
+                resident = slots >= 0
 
-        return fetch
+                def from_pool(a, acc):
+                    with jax.named_scope("expert_fetch"):
+                        leaves = em.leaf_blocks(
+                            lambda i: jax.lax.dynamic_index_in_dim(
+                                blocks, slots[a] * em.blocks + i, 0,
+                                keepdims=False))
+                    return apply(a, leaves, acc)
+
+                readers = (from_host, from_pool)
+            # branch 0: a padding entry; 1: the host store; 2: the pool
+            branch = jnp.where(live, 1 + resident.astype(jnp.int32), 0)
+            for a in range(sel.shape[0]):
+                acc = jax.lax.switch(branch[a], (lambda acc: acc,) + tuple(
+                    functools.partial(f, a) for f in readers), acc)
+            n_pool = jnp.sum(live & resident, dtype=jnp.int32)
+            n_host = jnp.sum(live, dtype=jnp.int32) - n_pool
+            return acc, jnp.stack([n_host, n_pool])
+
+        def stacked(sel, n_act):
+            def put(a, leaves, acc):          # the span's blocks in order
+                return acc.at[a].set(jnp.stack(
+                    [b for e in em.leaves for b in leaves[e.path[-1]]]))
+
+            spans, reads = each(sel, n_act, put, jnp.zeros(
+                sel.shape + em.span_shape, self.pages.dtype))
+            return _paging.unflatten_expert_span(spans, em), reads
+
+        return ExpertFetch(each, stacked)
 
 
 # ---------------------------------------------------------------------------
@@ -124,8 +169,8 @@ def block_apply(cfg: ModelConfig, spec: LayerSpec, p: Dict, x, *,
     """Returns (x, new_cache, new_xattn_cache, aux_loss, expert).
 
     With ``expert_fetch`` set (expert-granular paged weights), the MoE FFN
-    runs the two-phase step: router first, then a gather of only the
-    activated experts' page spans; ``expert`` is (counts (E,), reads
+    runs the two-phase step: router first, then only the activated
+    experts' spans are read and applied; ``expert`` is (counts (E,), reads
     (2,)): the routing, so the host-side residency cache can learn
     popularity and account hits/misses, and the spans the fetch read
     from the host store and from the pool.  Otherwise expert is None.
@@ -340,14 +385,14 @@ def forward(cfg: ModelConfig, params, tokens, *, cache=None, mode="train",
     expert-granular path: the scan streams only each layer's *shared*
     span and the MoE experts are fetched router-gated per layer.
     `expert_state` then optionally maps each MoE group key to
-    (pool (slots, ppe, page_elems), resident_map (L, E) int32): spans
+    (pool (slots, *span_shape), resident_map (L, E) int32): spans
     whose map entry is >= 0 are read in place from the device pool,
     the rest stream from the host store.  The result dict gains
     "expert_counts" ({key: (n_steps, E)} tokens-routed counts) so the
     host residency cache can learn popularity and account traffic, and
     "expert_reads" ({key: (n_steps, 2)}: per layer, the spans the
     program read from the host store and from the pool, counted in the
-    fetch's branches, padding entries of the activated set included)."""
+    fetch's branches; padding entries of the activated set read none)."""
     B, S = tokens.shape
     if mode == "decode":
         assert cache is not None

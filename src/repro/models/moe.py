@@ -76,6 +76,20 @@ def gated_ffn(cfg: ModelConfig, wi, wo, x):
     return jnp.einsum("...f,fd->...d", y, wo.astype(x.dtype))
 
 
+def expert_ffn(cfg: ModelConfig, gate, up, down_t, x):
+    """One routed expert's gated FFN on its weights in the layout an
+    expert span stores them (``paging.EXPERT_STORAGE_PERM``): ``gate`` and
+    ``up`` are wi's halves (D, F), ``down_t`` is wo transposed (D, F).
+    x: (..., D).  The same dot products as ``gated_ffn``; each projection
+    is a 2-D dot on one block, so on TPU each reads its block where the
+    span lies."""
+    dt = x.dtype
+    g = jnp.einsum("...d,df->...f", x, gate.astype(dt))
+    u = jnp.einsum("...d,df->...f", x, up.astype(dt))
+    y = act_fn(cfg.ffn_act)(g) * u
+    return jnp.einsum("...f,df->...d", y, down_t.astype(dt))
+
+
 def gated_ffn_partial_in(cfg, wi, wo, x):
     """Same as gated_ffn but wi/wo hold only an F-shard; the caller must
     psum the result over the sharded axis."""
@@ -364,8 +378,8 @@ def activated_experts(idx, num_experts: int, max_active: int
 
     sel (max_active,): activated expert ids in ascending order, padded with
     0 beyond n_act (padding slots never receive tokens — the index map only
-    targets real compact slots, and subset compute masks them to a weight
-    of exactly zero).  index_map (E,): expert id → compact slot, -1 if not
+    targets real compact slots — and the paged fetch reads nothing for
+    them).  index_map (E,): expert id → compact slot, -1 if not
     activated.  ``max_active`` must be ≥ min(E, T*K) for exactness; the
     callers derive it from static shapes so this always holds."""
     hit = jnp.zeros((num_experts,), bool).at[idx.reshape(-1)].set(True)
@@ -374,20 +388,31 @@ def activated_experts(idx, num_experts: int, max_active: int
     return sel, index_map, jnp.sum(hit).astype(jnp.int32)
 
 
-def _dense_subset(cfg: ModelConfig, ep: Dict, x, w, idx, sel, n_act):
-    """Dense-oracle compute on a compacted expert subset.  Accumulates in
-    ascending activated-expert order, so the result matches ``moe_dense``
-    bit-for-bit up to ±0 (the experts it skips contribute exactly zero
-    there)."""
-    A = ep["wi"].shape[0]
-    wi_all, wo_all = expert_weights(ep, x.dtype)
-    out = jnp.zeros_like(x, dtype=jnp.float32)
-    for a in range(A):
-        y = gated_ffn(cfg, wi_all[a], wo_all[a], x)
-        we = jnp.sum(jnp.where(idx == sel[a], w, 0.0), axis=-1)     # (T,)
-        we = jnp.where(a < n_act, we, 0.0)     # mask pad slots (sel[a] == 0)
-        out = out + y.astype(jnp.float32) * we[:, None]
-    return out.astype(x.dtype)
+def _weighted_entry(cfg: ModelConfig, p: Dict, x, w, idx, sel):
+    """apply(a, leaves, acc) for ``fetch_experts.each``: entry a's
+    routing-weighted expert output added to the float32 accumulator.
+    ``leaves`` are the expert's leaves in their storage layout
+    (``paging.ExpertManifest.leaf_blocks``).  Entries arrive in ascending
+    activated-expert order, so the sum matches ``moe_dense`` bit-for-bit
+    up to ±0 (the experts it skips contribute exactly zero there)."""
+    dt = x.dtype
+
+    def apply(a, leaves, acc):
+        with jax.named_scope("moe_ffn"):
+            gate, up = leaves["wi"]
+            (down_t,) = leaves["wo"]
+            if "wi_scale" in p:
+                # int8 dequant scales live in the shared span (see
+                # paging.EXPERT_LEAF_NAMES)
+                si = p["wi_scale"][sel[a]].astype(dt)
+                so = p["wo_scale"][sel[a]].astype(dt)
+                gate, up = gate.astype(dt) * si, up.astype(dt) * si
+                down_t = down_t.astype(dt) * so
+            y = expert_ffn(cfg, gate, up, down_t, x)
+            we = jnp.sum(jnp.where(idx == sel[a], w, 0.0), axis=-1)  # (T,)
+            return acc + y.astype(jnp.float32) * we[:, None]
+
+    return apply
 
 
 def _grouped_subset(cfg: ModelConfig, ep: Dict, x, w, idx, index_map,
@@ -432,26 +457,33 @@ def moe_paged(cfg: ModelConfig, p: Dict, x, *, fetch_experts,
               token_groups: Optional[int] = None
               ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Two-phase MoE step for expert-granular paged weights: run the
-    router FIRST, then fetch only the activated experts' page spans
-    (``fetch_experts(sel (A,)) -> ({wi (A,...), wo (A,...)[, scales]},
-    reads (2,))`` — resident spans read in place from the device pool,
-    misses stream from the host store) and compute on the compacted
-    subset.
+    router FIRST, then read only the activated experts' spans (resident
+    spans in place from the device pool, misses from the host store,
+    nothing for padding entries) through ``fetch_experts`` (a
+    ``models.model.ExpertFetch``):
+
+      * ``each(sel, n_act, apply, acc)`` applies every activated expert
+        straight from its span blocks and sums the weighted outputs —
+        the serving path: one expert's weights are live at a time and no
+        subset is ever stacked;
+      * ``stacked(sel, n_act)`` stacks the subset in the model layout
+        ({wi (A, D, 2, F), wo (A, F, D)}) for the policies whose compute
+        needs one operand for all experts: ``moe_impl="grouped"`` and
+        the Pallas ``moe_ffn`` kernel (``use_kernels``).
 
     x: (T, D).  Returns (out, aux_loss, counts (E,) int32 — tokens routed
     to each expert, the residency EWMA's observation — and the fetch's
-    ``reads``: [host-store reads, pool reads] over all A entries of the
-    activated set, its padding included).  Numerics match
-    moe_dense / moe_grouped on the full expert set (skipped experts
-    contribute exactly zero there), so greedy transcripts are
-    bit-identical to whole-layer streaming.
+    ``reads``: [host-store reads, pool reads], one per activated expert,
+    none for padding).  Numerics match moe_dense / moe_grouped on the
+    full expert set (skipped experts contribute exactly zero there), so
+    greedy transcripts are bit-identical to whole-layer streaming.
 
     token_groups=G (module-based batching): x concatenates G rotation
     groups' tokens group-major.  The activated set (and the span fetch)
-    then covers the UNION of the groups' routed experts — each streamed
-    span serves every group's staged tokens in one accumulation window —
+    then covers the UNION of the groups' routed experts — each read span
+    serves every group's staged tokens in one accumulation window —
     while per-group numerics stay bit-identical to G separate calls
-    (``_dense_subset`` accumulates the extra experts at exactly ±0;
+    (the dense path adds the extra experts at exactly ±0;
     ``_grouped_subset`` buckets with per-group capacity).  counts is
     then (G, E) so the host residency cache can book per-window traffic
     yet keep per-group router-ahead predictions."""
@@ -468,22 +500,22 @@ def moe_paged(cfg: ModelConfig, p: Dict, x, *, fetch_experts,
         else:
             counts = jnp.zeros((NE,), jnp.int32).at[flat_e].add(1)
         sel, index_map, n_act = activated_experts(idx, NE, A)
-    with jax.named_scope("expert_fetch"):
-        ep, reads = fetch_experts(sel)
-    with jax.named_scope("moe_ffn"):
-        if "wi_scale" in p:
-            # int8 dequant scales live in the shared span (see
-            # paging.EXPERT_LEAF_NAMES): gather the activated experts'
-            # scales
-            ep = dict(ep, wi_scale=p["wi_scale"][sel],
-                      wo_scale=p["wo_scale"][sel])
-        if policy is not None and policy.moe_impl == "grouped":
+    if policy is not None and policy.moe_impl == "grouped":
+        ep, reads = fetch_experts.stacked(sel, n_act)
+        with jax.named_scope("moe_ffn"):
+            if "wi_scale" in p:
+                ep = dict(ep, wi_scale=p["wi_scale"][sel],
+                          wo_scale=p["wo_scale"][sel])
             out = _grouped_subset(cfg, ep, x, w, idx, index_map,
                                   use_kernel=policy.use_kernels,
                                   token_groups=token_groups)
-        else:
-            out = _dense_subset(cfg, ep, x, w, idx, sel, n_act)
-        if cfg.num_shared_experts:
+    else:
+        acc, reads = fetch_experts.each(
+            sel, n_act, _weighted_entry(cfg, p, x, w, idx, sel),
+            jnp.zeros(x.shape, jnp.float32))
+        out = acc.astype(x.dtype)
+    if cfg.num_shared_experts:
+        with jax.named_scope("moe_ffn"):
             out = out + gated_ffn(cfg, p["shared"]["wi"], p["shared"]["wo"],
                                   x)
     return out, aux, counts, reads

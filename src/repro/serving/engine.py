@@ -360,7 +360,7 @@ class Engine:
                     self._predictors[key] = residency.GatePredictor(
                         em.num_layers, em.num_experts)
                 self._expert_pool[key] = jnp.zeros(
-                    (max(1, slots), em.pages_per_expert, em.page_elems),
+                    (max(1, slots),) + em.span_shape,
                     pw.expert_pages[key].dtype)
             # one span per call, sliced from the (pinned-host on TPU)
             # store and moved to device memory inside the jit
@@ -744,9 +744,9 @@ class Engine:
         is what the program moved: under the dispatch snapshot, frozen
         for the whole call, a missed span is read again at every pass.
         ``pad_reads`` cross-checks the count from the routing: the host
-        reads that no activated expert asked for, i.e. padding entries
-        of ``moe.activated_experts`` whose expert-0 span was not
-        resident."""
+        reads that no activated expert asked for.  The fetch reads
+        nothing for the padding entries of ``moe.activated_experts``,
+        so it stays 0."""
         for key, arr in reads.items():
             a = np.asarray(arr).reshape(-1, 2).sum(axis=0)
             act = np.asarray(counts[key]) > 0
@@ -899,10 +899,10 @@ class Engine:
 
         Counted in the programs (expert-granular path): ``read_spans``,
         the fetch branches that read a span from the host store, every
-        pass and padding entry included; ``read_bytes`` = read_spans ×
-        span bytes; ``pool_reads``, the branches that read the device
-        pool; ``pad_reads``, the host reads no activated expert asked
-        for (padding of the activated set; see ``_book_reads``).
+        pass included; ``read_bytes`` = read_spans × span bytes;
+        ``pool_reads``, the branches that read the device pool;
+        ``pad_reads``, the host reads no activated expert asked for (0:
+        padding entries read nothing; see ``_book_reads``).
 
         ``host_s``: host seconds in the weight-paging layer's spans
         (``repro.weights.book`` and ``repro.weights.prefetch``).

@@ -144,7 +144,9 @@ def test_split_pack_roundtrip(rng, page_elems):
         assert "wi" not in got["moe"]
     # expert spans: exact per-(layer, expert) reconstruction
     em = sm.experts
-    assert experts.shape == (L, E, em.pages_per_expert, em.page_elems)
+    D, F = 6, 10
+    assert em.span_shape == (3, D, F)
+    assert experts.shape == (L, E, 3, D, F)
     for layer in range(L):
         for e in range(E):
             got = paging.unflatten_expert_span(experts[layer, e], em)
@@ -156,10 +158,16 @@ def test_split_pack_roundtrip(rng, page_elems):
     sel = jnp.asarray([2, 0, 1], jnp.int32)
     got = paging.unflatten_expert_span(experts[1][sel], em)
     np.testing.assert_array_equal(got["wi"], tree["moe"]["wi"][1][sel])
-    # page-id table: dense, disjoint cover of the flat pool
-    ids = np.concatenate([em.expert_pages(l, e)
-                          for l in range(L) for e in range(E)])
-    assert sorted(ids.tolist()) == list(range(L * E * em.pages_per_expert))
+    # the blocks the FFN reads in place: gate, up, then wo transposed
+    wi, wo = tree["moe"]["wi"][2, 3], tree["moe"]["wo"][2, 3]
+    for block, want in zip(experts[2, 3], (wi[:, 0], wi[:, 1], wo.T)):
+        np.testing.assert_array_equal(block, want)
+    # ... and the same blocks by leaf name, as the fetch hands them over
+    leaves = em.leaf_blocks(lambda i: experts[2, 3][i])
+    assert sorted(leaves) == ["wi", "wo"]
+    for got, want in zip(leaves["wi"] + leaves["wo"],
+                         (wi[:, 0], wi[:, 1], wo.T)):
+        np.testing.assert_array_equal(got, want)
 
 
 def test_split_pack_without_experts_matches_whole_layer(rng):
@@ -203,6 +211,7 @@ def test_pack_block_groups_split_shapes(rng):
     em = pw.expert_manifests["p0"]
     assert pw.pages["p0"].shape[0] == em.num_layers == 3
     assert em.num_experts == 4
-    assert em.span_bytes == em.pages_per_expert * em.page_elems * 4
+    assert pw.expert_pages["p0"].shape == (3, 4) + em.span_shape
+    assert em.span_bytes == 3 * 6 * 10 * 4       # no padding
     assert pw.shared_layer_bytes("p0") == \
         pw.manifests["p0"].pages_per_layer * 64 * 4

@@ -352,19 +352,17 @@ def _recording_engine(cfg, params, **kw):
     return eng, calls
 
 
-def _expected_host_reads(cfg, tokens, resident, counts, window):
-    """Host reads per (pass, layer) from the routing alone: every
-    activated expert whose span the snapshot lacks, plus one read of
-    expert 0 per padding entry of the activated set when expert 0 is
-    not resident (``moe.activated_experts`` pads ``sel`` with 0)."""
+def _expected_reads(resident, counts, window):
+    """Reads per (pass, layer) from the routing alone: every activated
+    expert is read once, from the host store where the snapshot lacks
+    its span and from the pool where it holds it; the padding entries of
+    the activated set (``moe.activated_experts`` pads ``sel`` with 0)
+    read nothing."""
     act = counts > 0
     if window:
         act = act.any(axis=-2)
     act = act.reshape(-1, *resident.shape)             # (passes, L, E)
-    A = min(cfg.num_experts, tokens * cfg.top_k)
-    asked = (act & ~resident).sum(-1)
-    pad = (A - act.sum(-1)) * ~resident[:, 0]
-    return asked, pad
+    return (act & ~resident).sum(-1), act.sum(-1)
 
 
 @pytest.mark.parametrize("module_batch", [False, True],
@@ -372,9 +370,10 @@ def _expected_host_reads(cfg, tokens, resident, counts, window):
 def test_program_counts_every_host_read(mixtral_setup, module_batch):
     """With a chunk of 8 and a mostly empty pool, the reads the programs
     count equal, per pass and layer, an independent count from the
-    dispatch snapshot and the routing (padding entries included); the
-    engine's totals are their sums, and the bytes the programs read
-    exceed what the host books."""
+    dispatch snapshot and the routing: host reads are the activated
+    non-resident experts, host + pool reads the activated experts, and
+    padding entries read nothing.  The engine's totals are their sums,
+    and the bytes the programs read exceed what the host books."""
     cfg, params = mixtral_setup
     eng, calls = _recording_engine(cfg, params, expert_slots=2,
                                    decode_chunk=8, module_batch=module_batch)
@@ -383,25 +382,117 @@ def test_program_counts_every_host_read(mixtral_setup, module_batch):
         eng.submit(rng.integers(2, cfg.vocab_size, int(rng.integers(4, 20))),
                    12)
     eng.run_until_idle()
-    host = pool = pads = 0
+    host = pool = padded = 0
     for tokens, resident, counts, reads, window in calls:
-        asked, pad = _expected_host_reads(cfg, tokens, resident, counts,
-                                          window)
+        asked, activated = _expected_reads(resident, counts, window)
         got = reads.reshape(-1, *reads.shape[-2:])       # (passes, L, 2)
         A = min(cfg.num_experts, tokens * cfg.top_k)
-        np.testing.assert_array_equal(got[..., 0], asked + pad)
-        np.testing.assert_array_equal(got[..., 0] + got[..., 1], A)
+        np.testing.assert_array_equal(got[..., 0], asked)
+        np.testing.assert_array_equal(got[..., 0] + got[..., 1], activated)
         host += int(got[..., 0].sum())
         pool += int(got[..., 1].sum())
-        pads += int(pad.sum())
+        padded += int((A - activated).sum())
     assert any(w for *_, w in calls) == module_batch
     t = eng.weight_traffic()
     span = eng.residency["p0"].span_bytes
-    assert (t["read_spans"], t["pool_reads"], t["pad_reads"]) \
-        == (host, pool, pads)
-    assert pads > 0 and pool > 0
+    assert (t["read_spans"], t["pool_reads"]) == (host, pool)
+    # the activated sets had padding, and none of it was read
+    assert padded > 0 and t["pad_reads"] == 0
+    assert pool > 0
     assert t["read_bytes"] == host * span
     assert t["read_bytes"] > t["expert_bytes"] > 0
+
+
+# ---------------------------------------------------------------------------
+# The per-entry fetch: each activated expert applied from its span
+# ---------------------------------------------------------------------------
+
+def _paged_moe_case(expert_dtype, seed=3):
+    """A bf16 mixtral smoke layer, its packed expert store, and the moe
+    params of layer 1."""
+    import jax
+    from repro.configs import get_config
+    from repro.core import paging
+    from repro.models.params import init_params
+    cfg = dataclasses.replace(get_config("mixtral-8x7b").smoke(),
+                              dtype="bfloat16", expert_dtype=expert_dtype)
+    params = init_params(cfg, jax.random.key(seed))
+    pw = paging.pack_block_groups_split(params["blocks"], 4096)
+    p = jax.tree.map(lambda a: a[1], params["blocks"]["p0"]["moe"])
+    return cfg, p, pw.expert_pages["p0"], pw.expert_manifests["p0"]
+
+
+def _expert_ctx(store, em, resident):
+    """An _ExpertCtx whose pool holds layer 1's ``resident`` experts."""
+    import jax.numpy as jnp
+    from repro.models.model import _ExpertCtx
+    rmap = np.full((em.num_layers, em.num_experts), -1, np.int32)
+    rmap[1, resident] = np.arange(len(resident))
+    pool = jnp.asarray(np.asarray(store)[1, resident])
+    return _ExpertCtx(jnp.asarray(store), em, pool, jnp.asarray(rmap))
+
+
+@pytest.mark.parametrize("token_groups", [None, 2], ids=["lockstep", "window"])
+@pytest.mark.parametrize("expert_dtype", ["", "int8"], ids=["bf16", "int8"])
+def test_paged_moe_matches_dense_bit_for_bit(expert_dtype, token_groups):
+    """For random routings whose activated set is shorter than the fetch
+    (padding entries), with experts read from the pool and from the host
+    store, moe_paged applying each expert straight from its span equals
+    moe_dense on the full expert set bit-for-bit, and reads each
+    activated expert once."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import moe
+    cfg, p, store, em = _paged_moe_case(expert_dtype)
+    rng = np.random.default_rng(5)
+    seen = {"pad": 0, "pool": 0, "host": 0}
+    for trial in range(6):
+        T = (2, 4)[trial % 2] * (token_groups or 1)
+        resident = rng.choice(cfg.num_experts, 3, replace=False)
+        ctx = _expert_ctx(store, em, resident)
+        x = jnp.asarray(rng.normal(0, 1, (T, cfg.d_model)), jnp.bfloat16)
+
+        @jax.jit
+        def step(x):
+            return moe.moe_paged(cfg, p, x, fetch_experts=ctx.make_fetch(
+                jnp.int32(1)), token_groups=token_groups)
+
+        out, _, counts, reads = step(x)
+        ref, _ = jax.jit(lambda x: moe.moe_dense(cfg, p, x))(x)
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(ref, np.float32))
+        act = np.asarray(counts).reshape(-1, cfg.num_experts).sum(0) > 0
+        in_pool = np.isin(np.arange(cfg.num_experts), resident)
+        np.testing.assert_array_equal(
+            np.asarray(reads), [(act & ~in_pool).sum(), (act & in_pool).sum()])
+        seen["pad"] += min(cfg.num_experts, T * cfg.top_k) - int(act.sum())
+        seen["pool"] += int(reads[1])
+        seen["host"] += int(reads[0])
+    assert all(seen.values()), seen
+
+
+def test_paged_moe_stacks_no_expert_subset():
+    """The lowered program of one paged MoE step holds one switch per
+    entry of the activated set and no concatenate as large as the
+    activated experts' weights (the fetch used to stack the spans)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from repro.models import moe
+    cfg, p, store, em = _paged_moe_case("")
+    ctx = _expert_ctx(store, em, np.array([0, 5]))
+    T = 4
+    A = min(cfg.num_experts, T * cfg.top_k)
+    x = jnp.zeros((T, cfg.d_model), jnp.bfloat16)
+    text = jax.jit(lambda x: moe.moe_paged(
+        cfg, p, x, fetch_experts=ctx.make_fetch(jnp.int32(1)))).lower(
+        x).as_text()
+    assert text.count("stablehlo.case") == A
+    span = int(np.prod(em.span_shape))
+    for dims in re.findall(r"stablehlo\.concatenate.*-> tensor<([\dx]+)x",
+                           text):
+        assert int(np.prod([int(d) for d in dims.split("x")])) < span, dims
 
 
 def _span_events(trace_dir):

@@ -149,30 +149,39 @@ def test_flash_prefill_compiles(one_chip):
 
 
 def test_expert_fetch_from_pinned_host_store(one_chip):
-    """The router-gated expert fetch moves only the selected experts'
-    spans: the store is host argument bytes, and the device temp bytes
-    stay below one layer's full expert slice."""
+    """One paged MoE step at decode width: the router-gated fetch moves
+    only the activated experts' spans and applies each from the layout it
+    is stored in.  The store is host argument bytes, and the device temp
+    bytes stay below two spans: one expert's weights are live at a time,
+    with no stacked subset and no transposed copy."""
+    from repro.configs import get_config
+    from repro.models import moe
+
     host = one_chip.with_memory_kind("pinned_host")
+    cfg = get_config("mixtral-8x7b")
     L = 2
     leaves = [(("moe", "wi"), jax.ShapeDtypeStruct(
                   (L, E, D_MODEL, 2, D_FF), jnp.bfloat16)),
               (("moe", "wo"), jax.ShapeDtypeStruct(
                   (L, E, D_FF, D_MODEL), jnp.bfloat16))]
-    em = paging.expert_manifest(leaves, 1 << 16)
-    span = (em.pages_per_expert, em.page_elems)
+    em = paging.expert_manifest(leaves)
+    assert em.span_shape == (3, D_MODEL, D_FF)
     slots = 4
-    store = jax.core.ShapedArray((L, E) + span, jnp.bfloat16,
+    store = jax.core.ShapedArray((L, E) + em.span_shape, jnp.bfloat16,
                                  memory_space=jax.memory.Space.Host)
     args = (store,
-            _shape(one_chip, (slots,) + span, jnp.bfloat16),
+            _shape(one_chip, (slots,) + em.span_shape, jnp.bfloat16),
             _shape(one_chip, (L, E), jnp.int32),
             _shape(one_chip, (), jnp.int32),
-            _shape(one_chip, (TOP_K,), jnp.int32))
+            _shape(one_chip, (D_MODEL, E), jnp.bfloat16),
+            _shape(one_chip, (B, D_MODEL), jnp.bfloat16))
 
-    def fn(pages, pool, resident_map, layer, sel):
-        return _ExpertCtx(pages, em, pool, resident_map).make_fetch(layer)(sel)
+    def fn(pages, pool, resident_map, layer, router, x):
+        fetch = _ExpertCtx(pages, em, pool, resident_map).make_fetch(layer)
+        return moe.moe_paged(cfg, {"router": router}, x,
+                             fetch_experts=fetch)
 
-    compiled = _compile(fn, *args, in_shardings=(host,) + (one_chip,) * 4)
+    compiled = _compile(fn, *args, in_shardings=(host,) + (one_chip,) * 5)
     ma = compiled.memory_analysis()
     assert ma.host_argument_size_in_bytes == L * E * em.span_bytes
-    assert ma.temp_size_in_bytes < E * em.span_bytes
+    assert ma.temp_size_in_bytes < 2 * em.span_bytes
