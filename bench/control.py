@@ -52,7 +52,7 @@ def main(argv=None) -> int:
         return 3
     run.enable_cache(ROOT)
     cell = spec.load_cell(args.workload)
-    ref = spec.load_reference(cell.config["reference"])
+    ref = cell.reference
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         out = serve.run_cell(cell, seed, args.seconds, False, t0)

@@ -1,5 +1,6 @@
 """Model FLOPs of the prompt and generated tokens processed in the window
-(bench/flops.py) over the window's seconds and the chip's bf16 peak, in
+(counted by the cell's reference module, ``prefill_flops`` and
+``decode_flops``) over the window's seconds and the chip's bf16 peak, in
 percent."""
 from bench import window
 

@@ -1,15 +1,32 @@
-"""Plain float32 reference of a decoder of GQA attention and routed
-experts, written from the configuration file's description and
-independent of the program under test.
+"""Everything the harness knows of a decoder of GQA attention and routed
+experts (Mixtral): the sizes it needs, its seeded weights and the
+engine's tree built from them, the work a token costs, and a plain
+float32 reference written from the configuration file's description and
+independent of the program under test.  bench/spec.py loads it by the
+name in the configuration file's ``reference`` and checks that it
+defines every one of ``spec.REFERENCE_EXPORTS``.
 
-Per layer: RMSNorm, grouped-query attention with rotary embeddings
-(rotate-half form, q head h reads kv head h // (H / Hkv), scale
-1/sqrt(head_dim), causal), residual; RMSNorm, a softmax router over all
-experts whose top-k experts are weighted by their softmax probabilities
-(not renormalised, as the configuration files state), SwiGLU experts
-(silu(x Wg) * (x Wu)) Wo, residual.  Then RMSNorm and the output head.
-Weights come from bench/weights.py, drawn again from the seed, layer by
-layer, so the reference never holds the whole model.
+Weights (per layer): ``attn`` {wq (D, H*Dh), wk, wv (D, Hkv*Dh), wo
+(H*Dh, D)}, ``attn_norm``/``ffn_norm`` {scale (D,)}, ``moe`` {router
+(D, E), wi (E, D, 2, F) with gate at [..., 0, :] and up at [..., 1, :],
+wo (E, F, D)}; top level: ``embed`` {tokens (V, D)}, ``final_norm``
+{scale (D,)}, ``lm_head`` (D, V).  Every layer is the engine's one-layer
+period, so the layers stack into ``blocks.p0``.
+
+Work: model FLOPs count one multiply-add as 2 operations and take only
+what a token needs: its projections, its router, its top-k experts,
+attention over the context it sees, and the output head for a generated
+token.  The paged-decode kernel's least work is one query row per
+sequence against the K and V of that sequence's live context.
+
+Reference, per layer: RMSNorm, grouped-query attention with rotary
+embeddings (rotate-half form, q head h reads kv head h // (H / Hkv),
+scale 1/sqrt(head_dim), causal), residual; RMSNorm, a softmax router over
+all experts whose top-k experts are weighted by their softmax
+probabilities (not renormalised, as the configuration files state),
+SwiGLU experts (silu(x Wg) * (x Wu)) Wo, residual.  Then RMSNorm and the
+output head.  Weights are drawn again from the seed, layer by layer, so
+the reference never holds the whole model.
 
 Matmuls run at ``highest`` precision.  ``fp8=True`` is the control: every
 matmul with a weight takes its operands through float8 e4m3 (weights
@@ -19,7 +36,7 @@ value) and computes the rest as above.
 from __future__ import annotations
 
 import functools
-from typing import List, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +46,133 @@ from bench import weights
 
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
+
+# the Pallas call shows in the trace as "paged_gqa_decode_fused.<n>"
+DECODE_KERNEL = "paged_gqa"
+
+
+# ------------------------------------------------------------------ sizes
+
+def dims(cfg) -> Dict[str, object]:
+    """The sizes the weights, counts and reference need."""
+    return {"d_model": cfg.d_model, "num_heads": cfg.num_heads,
+            "num_kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+            "d_ff": cfg.d_ff, "vocab_size": cfg.vocab_size,
+            "num_experts": cfg.num_experts, "top_k": cfg.top_k,
+            "num_layers": cfg.num_layers, "rope_theta": cfg.rope_theta,
+            "norm_eps": cfg.norm_eps}
+
+
+# ---------------------------------------------------------------- weights
+
+def _draw_layer(key, *, D, H, Hkv, Dh, F, E):
+    k = jax.random.split(key, 9)
+    normal, scale = weights.normal, weights.scale
+    return {
+        "attn": {"wq": normal(k[0], (D, H * Dh), D),
+                 "wk": normal(k[1], (D, Hkv * Dh), D),
+                 "wv": normal(k[2], (D, Hkv * Dh), D),
+                 "wo": normal(k[3], (H * Dh, D), H * Dh)},
+        "attn_norm": {"scale": scale(k[4], D)},
+        "ffn_norm": {"scale": scale(k[5], D)},
+        "moe": {"router": normal(k[6], (D, E), D),
+                "wi": normal(k[7], (E, D, 2, F), D),
+                "wo": normal(k[8], (E, F, D), F)},
+    }
+
+
+def _draw_top(key, *, D, V):
+    k = jax.random.split(key, 3)
+    return {"embed": {"tokens": weights.normal(k[0], (V, D), D)},
+            "final_norm": {"scale": weights.scale(k[1], D)},
+            "lm_head": weights.normal(k[2], (D, V), D)}
+
+
+@functools.lru_cache(maxsize=None)
+def _draw_layer_fn(D, H, Hkv, Dh, F, E):
+    return jax.jit(functools.partial(_draw_layer, D=D, H=H, Hkv=Hkv, Dh=Dh,
+                                     F=F, E=E))
+
+
+@functools.lru_cache(maxsize=None)
+def _draw_top_fn(D, V):
+    return jax.jit(functools.partial(_draw_top, D=D, V=V))
+
+
+def layer(dims: dict, seed: int, index: int) -> dict:
+    """Weights of layer ``index``, on the device."""
+    key = jax.random.fold_in(weights.base_key(seed), index + 1)
+    return _draw_layer_fn(dims["d_model"], dims["num_heads"],
+                          dims["num_kv_heads"], dims["head_dim"],
+                          dims["d_ff"], dims["num_experts"])(key)
+
+
+def top(dims: dict, seed: int) -> dict:
+    """Embedding, final norm and output head, on the device."""
+    key = jax.random.fold_in(weights.base_key(seed), 0)
+    return _draw_top_fn(dims["d_model"], dims["vocab_size"])(key)
+
+
+def engine_params(cfg, dims: dict, seed: int) -> dict:
+    """The engine's parameter tree: layers drawn on the device one at a
+    time and kept in host memory, stacked into the one-layer period
+    ``blocks.p0``; the top level on the device."""
+    layers = [jax.device_get(layer(dims, seed, i))
+              for i in range(dims["num_layers"])]
+    params = dict(top(dims, seed))
+    params["blocks"] = {"p0": jax.tree.map(lambda *xs: np.stack(xs),
+                                           *layers)}
+    return params
+
+
+# ------------------------------------------------------------------- work
+
+def layer_flops(d: dict, ctx: int) -> int:
+    """One token through one layer, attending over ``ctx`` positions."""
+    D, H, Hkv, Dh = d["d_model"], d["num_heads"], d["num_kv_heads"], \
+        d["head_dim"]
+    proj = 2 * D * (H * Dh + 2 * Hkv * Dh) + 2 * H * Dh * D
+    attn = 4 * H * Dh * ctx                      # q.k and p.v
+    router = 2 * D * d["num_experts"]
+    experts = d["top_k"] * 2 * 3 * D * d["d_ff"]
+    return proj + attn + router + experts
+
+
+def head_flops(d: dict) -> int:
+    return 2 * d["d_model"] * d["vocab_size"]
+
+
+def prefill_flops(d: dict, prompt_len: int) -> int:
+    """A prompt, causal (position i sees i + 1 positions), and the head
+    at its last position."""
+    n = prompt_len
+    per_layer = n * layer_flops(d, 0) + 4 * d["num_heads"] * d["head_dim"] \
+        * n * (n + 1) // 2
+    return d["num_layers"] * per_layer + head_flops(d)
+
+
+def decode_flops(d: dict, ctx: int) -> int:
+    """One generated token whose query sees ``ctx`` positions."""
+    return d["num_layers"] * layer_flops(d, ctx) + head_flops(d)
+
+
+def paged_decode_work(d: dict, ctx: int, kv_bytes: int = 2):
+    """(flops, bytes) of one sequence's decode attention in one layer:
+    read its q, the K and V of ``ctx`` positions, write its output."""
+    H, Hkv, Dh = d["num_heads"], d["num_kv_heads"], d["head_dim"]
+    flops = 4 * H * Dh * ctx
+    nbytes = 2 * ctx * Hkv * Dh * kv_bytes + 2 * H * Dh * kv_bytes
+    return flops, nbytes
+
+
+def decode_kernel_work(d: dict, ctx: int):
+    """(flops, bytes) of the paged-decode kernel for one generated token
+    whose query sees ``ctx`` positions: every layer runs it once."""
+    flops, nbytes = paged_decode_work(d, ctx)
+    return d["num_layers"] * flops, d["num_layers"] * nbytes
+
+
+# -------------------------------------------------------------- reference
 
 
 def _fp8(x, axis):
@@ -138,13 +282,13 @@ def gaps(dims: dict, seed: int, seqs, *, control: bool = False
     position, by the token the float8 reference puts first, and the gap
     of that token is returned instead."""
     toks = _batch(seqs)
-    top = weights.top(dims, seed)
-    h = top["embed"]["tokens"][jnp.asarray(toks)].astype(jnp.float32)
+    w_top = top(dims, seed)
+    h = w_top["embed"]["tokens"][jnp.asarray(toks)].astype(jnp.float32)
     items = tuple(sorted(dims.items()))
     h8 = h
     with jax.default_matmul_precision("highest"):
         for i in range(dims["num_layers"]):
-            w = weights.layer(dims, seed, i)
+            w = layer(dims, seed, i)
             h = _layer_fn(items, False)(h, w)
             if control:
                 h8 = _layer_fn(items, True)(h8, w)
@@ -156,10 +300,10 @@ def gaps(dims: dict, seed: int, seqs, *, control: bool = False
             lo, n = len(p) - 1, len(s)
             tgt = jnp.asarray(_targets(p, s, toks.shape[1]))
             if control:
-                _, first8 = head8(h8[b], top["final_norm"]["scale"],
-                                  top["lm_head"], tgt)
+                _, first8 = head8(h8[b], w_top["final_norm"]["scale"],
+                                  w_top["lm_head"], tgt)
                 tgt = first8
-            gap, _ = head(h[b], top["final_norm"]["scale"], top["lm_head"],
-                          tgt)
+            gap, _ = head(h[b], w_top["final_norm"]["scale"],
+                          w_top["lm_head"], tgt)
             out.append(np.asarray(gap)[lo:lo + n])
     return out

@@ -61,14 +61,13 @@ def enable_cache(root: Path) -> str:
 
 def checks(cell, out) -> dict:
     """The numbers compared with the reference, each with its limit."""
-    ref = spec.load_reference(cell.config["reference"])
     seqs = out["seqs"]
     limits = cell.settings["check"]["limits"]
     if not seqs:
         return {"served_tokens": {"value": 0,
                                   "limit": cell.settings["check"]
                                   ["sample_tokens"]}}
-    gaps = ref.gaps(out["dims"], out["seed"], seqs)
+    gaps = cell.reference.gaps(out["dims"], out["seed"], seqs)
     flat = [float(g) for row in gaps for g in row]
     # the widest gap is read but not compared: one near-tied router
     # choice sets it, and the control's widest gap overlaps the program's

@@ -1,6 +1,10 @@
 """One benchmark run of a serving cell: build, warm up, measure, check.
 
-The engine is the program's ``repro.serving.engine.Engine`` with the
+What belongs to the architecture comes from the cell's reference module
+(``cell.reference``, bench/references/<reference>.py): the sizes, the
+engine's parameter tree drawn from the seed, and the model and
+decode-kernel work each admitted prompt and generated token costs.  The
+engine is the program's ``repro.serving.engine.Engine`` with the
 cell's ``EngineConfig`` fields (every other field at its default) and
 ``ExecPolicy``.  The harness drives it through ``submit`` and ``step``
 as a closed loop of offline batches: before every tick it tops the
@@ -21,7 +25,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import flops, spec, window
+from bench import spec, window
 from bench.traffic import Traffic
 
 MAX_WARM_TICKS = 16
@@ -79,23 +83,14 @@ class RunRecord:
     trace_window: Optional[tuple] = None
 
 
-def _params(cfg, dims, seed):
-    """The engine's parameter tree: layers drawn on the device one at a
-    time and kept in host memory, the top level on the device."""
+def _params(ref, cfg, dims, seed):
+    """The reference module's engine tree, checked leaf by leaf against
+    the layout the engine expects."""
     import jax
 
-    from bench import weights
     from repro.models.params import abstract_params
 
-    layers = []
-    for i in range(dims["num_layers"]):
-        w = weights.layer(dims, seed, i)
-        layers.append(jax.device_get(w))
-        del w
-    params = dict(weights.top(dims, seed))
-    params["blocks"] = {"p0": jax.tree.map(lambda *xs: np.stack(xs),
-                                           *layers)}
-    del layers
+    params = ref.engine_params(cfg, dims, seed)
     want = abstract_params(cfg)
     have = jax.tree.map(lambda a: (tuple(a.shape), str(a.dtype)), params)
     need = jax.tree.map(lambda a: (tuple(a.shape), str(a.dtype)), want)
@@ -109,9 +104,10 @@ class Loop:
     """Closed-loop runner of one engine: ``tick()`` tops up the queue,
     runs one ``Engine.step`` and logs it."""
 
-    def __init__(self, eng, traffic: Traffic, dims: dict):
+    def __init__(self, eng, traffic: Traffic, ref, dims: dict):
         self.eng = eng
         self.traffic = traffic
+        self.ref = ref
         self.dims = dims
         self.sched = eng.scheduler
         self.ubatch = len(self.sched.slots[0])
@@ -158,6 +154,7 @@ class Loop:
         admitted = {rid for rid in queued
                     if sched.requests[rid].generated}
         tokens, live_rows = {}, [0] * len(sched.slots)
+        ref, dims = self.ref, self.dims
         for rid in set(live) | admitted:
             r = sched.requests[rid]
             g0 = before.get(rid, 0)
@@ -167,15 +164,15 @@ class Loop:
             start = g0 + (1 if rid in admitted else 0)
             p = self.prompt_len[rid]
             if rid in admitted:
-                self.model_flops += flops.prefill_flops(self.dims, p)
+                self.model_flops += ref.prefill_flops(dims, p)
             if n_dec > 0:
                 live_rows[gid_of[rid]] += 1
             for k in range(n_dec):
                 ctx = p + start + k
-                self.model_flops += flops.decode_flops(self.dims, ctx)
-                f, b = flops.paged_decode_work(self.dims, ctx)
-                self.kernel_flops += f * self.dims["num_layers"]
-                self.kernel_bytes += b * self.dims["num_layers"]
+                self.model_flops += ref.decode_flops(dims, ctx)
+                f, b = ref.decode_kernel_work(dims, ctx)
+                self.kernel_flops += f
+                self.kernel_bytes += b
             if r.done:
                 self.finished_at.setdefault(rid, self.n_ticks)
         self.n_ticks += 1
@@ -248,7 +245,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     from repro.serving.engine import Engine, EngineConfig
 
     cfg = spec.model_config(cell.config)
-    dims = spec.dims(cfg)
+    ref = cell.reference
+    dims = ref.dims(cfg)
     st = cell.settings
     dev = jax.devices()[0]
     peaks = spec.load_peaks()
@@ -259,7 +257,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
     counter = CompileCounter()
 
     t0 = time.perf_counter()
-    params = _params(cfg, dims, seed)
+    params = _params(ref, cfg, dims, seed)
     t_weights = time.perf_counter() - t0
     pol = dict(st.get("policy", {}))
     if impl is not None:
@@ -281,7 +279,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t_start: float,
         mutate(eng)
 
     traffic = Traffic(cell.traffic, seed, cfg.vocab_size)
-    loop = Loop(eng, traffic, dims)
+    loop = Loop(eng, traffic, ref, dims)
     kv_paged = st["engine"].get("kv_paged", False)
     pool_slots = loop.pool_rows
     quiet_run = 0

@@ -5,11 +5,17 @@ configurations and the metrics.  Everything that belongs to one of them
 sits in a file of its own under ``bench/``, found by name alone:
 
   bench/configs/<config>.json   model configuration as run
+  bench/references/<ref>.py     what the harness knows of an architecture
+                                (the configuration's ``reference``)
   bench/traffic/<traffic>.json  traffic mix, read by bench/traffic.py
   bench/cells/<workload>.json   engine settings and correctness limits
   bench/metrics/<metric>.py     reader of one metric: ``read(run)``
 
-Adding a cell, configuration, traffic mix or metric therefore means new
+A reference module is the harness's only knowledge of an architecture:
+the sizes it needs, its seeded weights and the engine's tree, the work a
+token costs, its decode kernel's name, and the plain reference that
+decides ``correct`` (``REFERENCE_EXPORTS``).  Adding a cell,
+configuration, architecture, traffic mix or metric therefore means new
 files and new entries in ``BENCHMARK.json``, and no edit elsewhere.
 """
 from __future__ import annotations
@@ -18,10 +24,26 @@ import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Callable, Dict, List, Optional
+from types import ModuleType
+from typing import Callable, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+
+# what every bench/references/<ref>.py defines:
+#   dims(cfg) -> dict              the sizes the rest needs
+#   layer(dims, seed, index)       seeded weights of one layer, on device
+#   top(dims, seed)                embedding, final norm, output head
+#   engine_params(cfg, dims, seed) the engine's parameter tree
+#   prefill_flops(dims, n)         model FLOPs of an n-token prompt
+#   decode_flops(dims, ctx)        model FLOPs of a token that sees ctx
+#   decode_kernel_work(dims, ctx)  (flops, bytes) of the decode kernel,
+#                                  all layers, for that token
+#   DECODE_KERNEL                  a fragment of that kernel's trace name
+#   gaps(dims, seed, seqs, control=False)   the correctness readings
+REFERENCE_EXPORTS = ("dims", "layer", "top", "engine_params",
+                     "prefill_flops", "decode_flops", "decode_kernel_work",
+                     "DECODE_KERNEL", "gaps")
 
 
 class SpecError(RuntimeError):
@@ -51,6 +73,7 @@ class Cell:
     name: str
     chips: int
     config: dict                # bench/configs/<config>.json
+    reference: ModuleType       # bench/references/<config's reference>.py
     traffic: dict               # bench/traffic/<traffic>.json
     settings: dict              # bench/cells/<name>.json
     end_to_end: List[Metric]
@@ -94,9 +117,12 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     if w["config"] not in configs:
         raise SpecError(f"workload {name!r} names unknown config "
                         f"{w['config']!r}")
+    config = _load_json(root / configs[w["config"]]["file"])
+    if "reference" not in config:
+        raise SpecError(f"config {w['config']!r} names no reference")
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_load_json(root / configs[w["config"]]["file"]),
+        name=name, chips=int(w["chips"]), config=config,
+        reference=load_reference(config["reference"], bench_dir),
         traffic=_load_json(bench_dir / "traffic" / f"{w['traffic']}.json"),
         settings=_load_json(bench_dir / "cells" / f"{name}.json"),
         end_to_end=_metrics(bench["end_to_end"], name, bench_dir, False),
@@ -122,23 +148,14 @@ def model_config(config: dict):
     return cfg
 
 
-def dims(cfg) -> Dict[str, object]:
-    """The sizes the harness's own weights and reference need."""
-    return {"d_model": cfg.d_model, "num_heads": cfg.num_heads,
-            "num_kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
-            "d_ff": cfg.d_ff, "vocab_size": cfg.vocab_size,
-            "num_experts": cfg.num_experts, "top_k": cfg.top_k,
-            "num_layers": cfg.num_layers, "rope_theta": cfg.rope_theta,
-            "norm_eps": cfg.norm_eps}
-
-
 def load_peaks(bench_dir: Path = BENCH_DIR) -> dict:
     """Peak rates by ``device_kind`` (bench/peaks.json, with its source)."""
     return _load_json(bench_dir / "peaks.json")
 
 
-def load_reference(name: str, bench_dir: Path = BENCH_DIR):
-    """The plain reference module a configuration file names."""
+def load_reference(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """The reference module a configuration file names, with every one of
+    ``REFERENCE_EXPORTS``."""
     path = bench_dir / "references" / f"{name}.py"
     if not path.is_file():
         raise SpecError(f"no reference {path}")
@@ -146,4 +163,8 @@ def load_reference(name: str, bench_dir: Path = BENCH_DIR):
         f"bench_reference_{name}", path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
+    missing = [e for e in REFERENCE_EXPORTS if not hasattr(mod, e)]
+    if missing:
+        raise SpecError(f"reference {path} does not define "
+                        f"{', '.join(missing)}")
     return mod

@@ -17,6 +17,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import copy  # noqa: E402
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -50,7 +51,8 @@ def main(argv=None) -> int:
     for pool in args.pools.split(","):
         ub, nu = (int(x) for x in pool.split("x"))
         for kv in kvs:
-            cell = copy.deepcopy(base)
+            cell = dataclasses.replace(
+                base, settings=copy.deepcopy(base.settings))
             cell.settings["engine"].update(ubatch=ub, num_ubs=nu,
                                            kv_gpu_ratio=kv)
             t0 = time.perf_counter()

@@ -49,13 +49,16 @@ TINY_CELL = {
 
 def make_root(tmp: Path, *, extra_metric: str = None) -> Path:
     """A checkout-shaped directory for the tiny cell: its own
-    BENCHMARK.json, config, traffic and cell files, the real metric
-    readers and the real program."""
+    BENCHMARK.json, config, traffic and cell files, the real reference
+    modules, metric readers and program."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     root = tmp / "root"
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
     (root / "bench" / "cells").mkdir()
+    (root / "bench" / "references").mkdir()
+    for ref in (REPO / "bench" / "references").glob("*.py"):
+        os.symlink(ref, root / "bench" / "references" / ref.name)
     os.symlink(REPO / "bench" / "metrics", root / "bench" / "metrics")
     os.symlink(REPO / "src", root / "src")
     (root / "bench" / "configs" / "tiny-moe.json").write_text(
